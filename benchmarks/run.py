@@ -26,6 +26,8 @@ def main() -> None:
                          "(for BENCH_*.json perf tracking)")
     args, _ = ap.parse_known_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows: list[tuple[str, float, str]] = []
     results: dict = {}

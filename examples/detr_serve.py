@@ -26,11 +26,18 @@ from repro.core.detector import detector_apply
 from repro.data.detection import eval_detection_ap, synth_detection_batch
 from repro.msda import available_backends, make_plan
 from repro.serve.engine import DetrRequest, DetrServeEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 DEFA_KW = dict(pap_mode="topk", pap_keep=6,
                fwp_mode="compact", fwp_k=1.0, fwp_capacity=0.6,
                range_narrow=(8.0, 6.0, 4.0, 3.0),
                act_bits=12, weight_bits=12)
+
+
+def _device() -> str:
+    """The device the timings above ran on, as JAX reports it."""
+    d = jax.devices()[0]
+    return f"{d.platform} {d.device_kind} x{len(jax.devices())}"
 
 
 def serve_encoder_head(args) -> None:
@@ -66,7 +73,7 @@ def serve_encoder_head(args) -> None:
               f"FWP kept {np.mean(fwp):.1%} of pixels, AP={aps[-1]:.3f}")
     dt = time.perf_counter() - t0
     print(f"\n[serve] {total} images in {dt:.2f}s = {total/dt:.2f} img/s "
-          f"(CPU; TPU projection comes from the dry-run roofline), "
+          f"({_device()}), "
           f"mean AP {np.mean(aps):.3f}")
 
 
@@ -109,7 +116,7 @@ def serve_decoder_head(args) -> None:
                                      np.stack([r.boxes for r in reqs]), gt))
     timed = len(done) - args.batch
     print(f"[serve/decoder] {len(done)} requests ({timed} timed) in "
-          f"{dt:.2f}s = {timed/max(dt, 1e-9):.2f} img/s (CPU), "
+          f"{dt:.2f}s = {timed/max(dt, 1e-9):.2f} img/s ({_device()}), "
           f"mean AP {np.mean(aps):.3f}")
 
 
@@ -164,6 +171,7 @@ def main():
                          "(JSONL trace export is driven by the "
                          "REPRO_OBS_JSONL env var)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.sustained:
         serve_sustained(args)
     elif args.decoder:

@@ -23,6 +23,7 @@ from repro.core import nn
 from repro.core.msdeform_attn import MSDeformAttnConfig, init_msdeform_attn
 from repro.serve.engine import StreamingDetrEngine
 from repro.stream import StreamConfig, drifting_scene
+from repro.utils.compile_cache import enable_compile_cache
 
 DRY_LEVELS = ((16, 20), (8, 10), (4, 5), (2, 3))
 FULL_LEVELS = ((32, 40), (16, 20), (8, 10), (4, 5))
@@ -74,6 +75,7 @@ def main():
     ap.add_argument("--dry-run", action="store_true",
                     help="tiny shapes / few layers (the CI smoke path)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     engine, levels, d = build_engine(args)
     print(f"[stream] {engine.describe()}")
@@ -131,7 +133,8 @@ def main():
     served = (args.frames - 1) * args.sessions
     print(f"\n[stream] {args.frames} frames x {args.sessions} sessions: "
           f"{served} timed frames in {dt:.2f}s = "
-          f"{served/max(dt, 1e-9):.2f} frames/s (CPU)")
+          f"{served/max(dt, 1e-9):.2f} frames/s "
+          f"({jax.devices()[0].platform} {jax.devices()[0].device_kind})")
     print(f"[stream] staged bytes: rebuild-per-frame "
           f"{r['rebuild_bytes_total']/1024:.0f} KB vs incremental "
           f"{r['staged_bytes_total']/1024:.0f} KB = "

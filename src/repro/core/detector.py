@@ -124,8 +124,10 @@ def encoder_backend(backend: Optional[str]) -> Optional[str]:
 
 
 def _pyramid(params, cfg: DetectorConfig, images: jnp.ndarray):
-    """images (B,3,S,S) -> list of 4 fmaps (B, w, H_l, W_l)."""
-    x = jax.nn.relu(nn.conv2d(params["stem"], images, stride=2))
+    """images (B,3,S,S) -> list of 4 fmaps (B, w, H_l, W_l), computed in
+    the detector's dtype."""
+    x = jax.nn.relu(nn.conv2d(params["stem"], images.astype(cfg.dtype),
+                              stride=2))
     feats = []
     for name in ("c1", "c2", "c3", "c4"):
         x = jax.nn.relu(nn.conv2d(params[name], x, stride=2))

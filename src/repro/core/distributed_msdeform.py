@@ -182,14 +182,9 @@ def msdeform_attn_banded(
     in_specs = (P(), P(bspec, axis, None), P(bspec, axis, None),
                 P(bspec, axis, None))
     out_specs = P(bspec, axis, None)
-    if hasattr(jax, "shard_map"):                    # jax >= 0.6
-        fn = jax.shard_map(body, mesh=mesh, axis_names=set(mesh.axis_names),
-                           in_specs=in_specs, out_specs=out_specs,
-                           check_vma=False)
-    else:                                            # 0.4.x experimental API
-        from jax.experimental.shard_map import shard_map as _shard_map
-        fn = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, axis_names=set(mesh.axis_names),
+                       in_specs=in_specs, out_specs=out_specs,
+                       check_vma=False)
     return fn(params, query, ref_points, x_flat)
 
 

@@ -66,9 +66,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
-from repro.kernels.msgs_fused import _eq4_sample_agg
+from repro.kernels.msgs_fused import sample_staged, stage_words
 
 
 @jax.tree_util.register_pytree_node_class
@@ -182,52 +181,11 @@ def update_staged_rows(staged: DecodeStagedTable,
 
 
 # --------------------------------------------------------------------------
-# kernel body — one (batch, head-group, query-tile, layer) grid step
+# launch — grid (batch, head-group, query-tile, layer), layer innermost
 # --------------------------------------------------------------------------
 
-def _make_decode_kernel(head_pack: int, dh: int, use_remap: bool,
-                        use_scale: bool):
-    """Kernel for grid (B, H/G, T_q, L); the staged table block is indexed
-    by (batch, head-group) only, so Pallas keeps it resident across the
-    whole (query-tile × layer) sweep — staged once per (b, head-group).
-    With ``use_scale`` the staged rows are int8 codes and the group's
-    (G·Dh,) f32 scale row rides in as one extra operand: 4 one-byte
-    corner loads per point plus one scale row, dequantized in-register
-    after aggregation."""
-    def kernel(*refs):
-        x_ref, y_ref, st_ref, wl_ref, hl_ref, p_ref = refs[:6]
-        refs = refs[6:]
-        remap = None
-        if use_remap:
-            remap, refs = refs[0][0], refs[1:]
-        v_ref = refs[0]
-        scale = refs[1][0, 0] if use_scale else None   # (G*Dh,)
-        o_ref = refs[-1]
-        vp = v_ref[0, 0]                          # (N_rows, G*Dh) staged
-        for j in range(head_pack):                # static unroll
-            o_ref[0, 0, :, j, :] = _eq4_sample_agg(
-                x_ref[0, 0, :, j, :], y_ref[0, 0, :, j, :],
-                st_ref[0, 0, :, j, :], wl_ref[0, 0, :, j, :],
-                hl_ref[0, 0, :, j, :], p_ref[0, 0, :, j, :],
-                vp, remap=remap, lanes=(j * dh, dh), scale=scale)
-    return kernel
-
-
-def _pad_q(nq: int, tq: int, x, y, probs, st, wl, hl):
-    """Pad the stacked (B, L, Nq, H, K) point axis to a tile multiple."""
-    pad = (-nq) % tq
-    if pad:
-        widths = ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))
-        zf = lambda a: jnp.pad(a, widths)
-        x, y, probs = zf(x), zf(y), zf(probs)
-        st = zf(st)
-        wl = jnp.pad(wl, widths, constant_values=1)
-        hl = jnp.pad(hl, widths, constant_values=1)
-    return pad, x, y, probs, st, wl, hl
-
-
 @functools.partial(jax.jit, static_argnames=(
-    "n_rows", "head_pack", "dh", "block_q", "interpret"))
+    "head_pack", "dh", "block_q", "interpret"))
 def _decode_pallas_call(
     vp: jnp.ndarray,                     # (B, n_groups, N_rows, G*Dh)
     x_px: jnp.ndarray,                   # (B, L, Nq, H, K)
@@ -239,52 +197,25 @@ def _decode_pallas_call(
     remap: Optional[jnp.ndarray],        # (B, N_pix) int32 or None
     scale: Optional[jnp.ndarray],        # (B, n_groups, G*Dh) f32 or None
     *,
-    n_rows: int, head_pack: int, dh: int,
+    head_pack: int, dh: int,
     block_q: int, interpret: bool,
 ) -> jnp.ndarray:
-    b, n_groups, _, gdh = vp.shape
-    _, n_layers, nq, h, k = x_px.shape
+    """The staged table's block is indexed by (batch, head-group) only, so
+    it stays resident in VMEM across the whole (query-tile x layer) sweep
+    — fetched once per (batch, head-group). With ``scale`` the staged rows
+    are int8 codes: four one-byte corner loads per point, aggregated in
+    f32 and dequantized once after aggregation."""
+    b, ng, n_rows, _ = vp.shape
     g = head_pack
-    tq = min(block_q, nq)
-    pad, x_px, y_px, probs, start, wl, hl = _pad_q(
-        nq, tq, x_px, y_px, probs, start, wl, hl)
-    nq_p = nq + pad
-
-    # layer axis INNERMOST: for one (b, head-group) the table block index
-    # never changes across the (query-tile x layer) sweep, so the staged
-    # block is fetched once per (batch, head-group) and revisited.
-    grid = (b, n_groups, nq_p // tq, n_layers)
-    pt = pl.BlockSpec((1, 1, tq, g, k),
-                      lambda bi, gi, qi, li: (bi, li, qi, gi, 0))
-    v_spec = pl.BlockSpec((1, 1, n_rows, gdh),
-                          lambda bi, gi, qi, li: (bi, gi, 0, 0))
-    out_spec = pl.BlockSpec((1, 1, tq, g, dh),
-                            lambda bi, gi, qi, li: (bi, li, qi, gi, 0))
-    out_dtype = vp.dtype if scale is None else probs.dtype
-    out_shape = jax.ShapeDtypeStruct((b, n_layers, nq_p, h, dh), out_dtype)
-
-    kernel = _make_decode_kernel(g, dh, use_remap=remap is not None,
-                                 use_scale=scale is not None)
-    in_specs = [pt, pt, pt, pt, pt, pt]
-    inputs = [x_px, y_px, start, wl, hl, probs]
-    name = "msgs_decode_persistent"
-    if remap is not None:
-        in_specs.append(pl.BlockSpec((1, remap.shape[1]),
-                                     lambda bi, gi, qi, li: (bi, 0)))
-        inputs.append(remap)
-    in_specs.append(v_spec)
-    inputs.append(vp)
-    if scale is not None:
-        in_specs.append(pl.BlockSpec((1, 1, gdh),
-                                     lambda bi, gi, qi, li: (bi, gi, 0)))
-        inputs.append(scale)
-        name += "_int8"
-    out = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs,
-        out_specs=out_spec, out_shape=out_shape,
-        interpret=interpret, name=name,
-    )(*inputs)
-    return out[:, :, :nq] if pad else out
+    words, _ = stage_words(vp.reshape(b, ng, n_rows, g, dh))
+    name = "msgs_decode_persistent" + ("_int8" if scale is not None else "")
+    out = sample_staged(words, x_px, y_px, start, wl, hl, probs, remap,
+                        dtype=jnp.dtype(vp.dtype), head_pack=g, dh=dh,
+                        block_q=block_q, name=name, interpret=interpret)
+    if scale is None:
+        return out.astype(vp.dtype)
+    s = scale.reshape(b, 1, 1, ng * g, dh).astype(jnp.float32)
+    return (out * s).astype(probs.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -320,7 +251,6 @@ def msgs_decode_ref(vp, x_px, y_px, start, wl, hl, probs, remap,
 
 class _DecodeStatic(NamedTuple):
     """Hashable static config for the custom_vjp entry point."""
-    n_rows: int
     head_pack: int
     dh: int
     block_q: int
@@ -336,7 +266,7 @@ def _msgs_decode(static: _DecodeStatic, vp, x_px, y_px, start, wl, hl,
                  probs, remap, scale):
     return _decode_pallas_call(
         vp, x_px, y_px, start, wl, hl, probs, remap, scale,
-        n_rows=static.n_rows, head_pack=static.head_pack, dh=static.dh,
+        head_pack=static.head_pack, dh=static.dh,
         block_q=static.block_q, interpret=static.interpret)
 
 
@@ -398,9 +328,8 @@ def msgs_decode_layers_pallas(
     """Stacked multi-layer persistent decode: ONE launch samples the
     staged table for all ``n_layers`` layers' points. Returns
     (B, n_layers, Nq, H, Dh). Differentiable (custom_vjp)."""
-    static = _DecodeStatic(n_rows=staged.n_rows, head_pack=staged.head_pack,
-                           dh=staged.dh, block_q=block_q,
-                           interpret=interpret)
+    static = _DecodeStatic(head_pack=staged.head_pack, dh=staged.dh,
+                           block_q=block_q, interpret=interpret)
     return _msgs_decode(static, staged.v, x_px, y_px,
                         start.astype(jnp.int32), wl.astype(jnp.int32),
                         hl.astype(jnp.int32), probs, staged.remap,

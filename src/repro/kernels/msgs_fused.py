@@ -1,31 +1,43 @@
 """Pallas TPU kernel: fused MSGS (bilinear grid-sampling) + aggregation.
 
-This is DEFA contribution C6 mapped to the TPU: one kernel computes corner
-indices, gathers the four neighbour rows from the value buffer resident in
-VMEM, evaluates the paper's 3-multiplier factorized bilinear form (Eq. 4)
+This is DEFA contribution C6 mapped to the TPU: one kernel gathers the
+four neighbour rows of every sampling point from the value table resident
+in VMEM and applies the bilinear x probability weights as it goes — the
+sampled values never round-trip through HBM (on the ASIC: never leave the
+PE array).
 
-    S = N0 + (N2-N0)·t0 + [(N1-N0) + (N3-N2-N1+N0)·t0]·t1
+**How the gather lowers.** The TPU's vector unit has no row gather from a
+VMEM table by a vector of indices, so the corner addressing is split
+between XLA and the kernel:
 
-and immediately applies the probability-weighted aggregation — the sampled
-values never round-trip through HBM (on the ASIC: never leave the PE array).
+  * outside the kernel (:func:`corner_operands`) XLA turns each point into
+    its four clipped corner rows — through the FWP-compact ``pix2slot``
+    remap when the table is compacted — and four effective weights,
+    ``bilinear x validity x probability``, in f32;
+  * inside, one grid step reads those as scalars from SMEM and loads each
+    corner row with a dynamic-offset ``pl.ds`` row load, multiplying it by
+    its scalar weight into a per-head accumulator.
+
+Eq. 4's three-multiplier factorization is the ASIC PE's multiplier count
+(``benchmarks/energy_model.py``); on the TPU the four-weight form is one
+scalar-vector multiply-add per corner and is what the kernel executes.
+
+**Table layout.** Heads are laid out on a leading axis: the table is
+staged as (B, H/G, N_rows, G·Dh) — ``G = head_pack`` heads side by side in
+one row, the same layout as the persistent decode staging — so a block's
+last two dims are whole axes. Dynamic row loads need 32-bit rows, so the
+staged rows are bit-packed into uint32 words (:func:`table_words`): a
+bf16 row carries two channels per word, an int8 row four; the kernel
+unpacks the word planes with shifts, so VMEM holds the table at its
+storage width. Each plane accumulates separately and the output is
+written as (…, planes, TQ, words) f32 blocks that XLA re-interleaves.
 
 C5 (inter-level parallelism) maps to the *layout*: the K point axis is
-level-major, so the per-lane gathers of one query spread across the disjoint
-per-level segments of the flat value buffer — the VMEM analogue of "4 points
-from 4 levels hit 4 disjoint bank groups". A cycle-accurate bank model
-(benchmarks/bank_sim.py) quantifies the ASIC-side claim.
-
-Grid: (B, H, Nq/TQ). The whole value table (N_rows, Dh) for one (batch,
-head) is staged in VMEM (DETR-scale fmaps fit comfortably: the paper's
-biggest multi-scale pyramid is ~9.8 MB *before* FWP, ~55% of that after,
-per-head slices are 1/8 of it). For fmaps beyond VMEM use the windowed
-variant (msgs_windowed.py) which exploits C3 range-narrowing + C7 reuse.
-
-TPU alignment note: Dh (typically 32 in DETR-family) is below the 128-lane
-width. ``msgs_fused_packed_pallas`` packs ``head_pack = 128 // Dh`` heads
-per 128-lane group (grid (B, H/G, Nq/TQ)): one staged (N_rows, G·Dh) table
-row carries G heads, so the lane groups that a padded layout would leave
-idle do real work. The MSDAPlan (repro/msda/plan.py) decides pad vs. pack.
+level-major, so the corner loads of one query spread across the disjoint
+per-level segments of the flat value buffer — the VMEM analogue of "4
+points from 4 levels hit 4 disjoint bank groups" (benchmarks/bank_sim.py
+models the ASIC side). For fmaps beyond VMEM use the windowed variant
+(msgs_windowed.py), which exploits C3 range-narrowing + C7 reuse.
 """
 from __future__ import annotations
 
@@ -35,110 +47,295 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.msda.sampling import corner_data
+
+#: Corner entries (index + weight) one grid step reads from SMEM. Two
+#: int32/f32 arrays double-buffered: 32768 x 4 B x 2 x 2 = 512 KiB of
+#: SMEM, which the v5e compiler accepts (tests/test_tpu_compile.py).
+SMEM_CORNERS = 32768
+
+#: Corner loads unrolled per loop iteration (one sampling point's four).
+CORNER_UNROLL = 4
+
+_U32 = jnp.uint32
+_LANES = 128
 
 
-def _eq4_sample_agg(x, y, st, wl, hl, probs, v,
-                    remap: Optional[jnp.ndarray] = None,
-                    lanes: Optional[Tuple[int, int]] = None,
-                    scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Shared Eq. 4 corner gather + factorized bilinear + aggregation.
+def word_planes(dtype) -> int:
+    """Channels per uint32 word for a table stored in ``dtype``."""
+    d = jnp.dtype(dtype)
+    return 1 if d == jnp.float16 else 4 // d.itemsize
 
-    x, y, st, wl, hl, probs: (TQ, K); v: (N_rows, Dv). ``remap`` is the
-    optional FWP-compact pixel -> slot indirection (N_pix,). ``lanes``
-    selects a (lo, n) lane slice of the gathered rows — used by the
-    head-packed layout where Dv = G·Dh holds G heads side by side.
-    ``scale`` is the int8 table's per-channel (Dv,) dequant scale: the
-    corners gather 1-byte codes, the bilinear/aggregation arithmetic runs
-    in the compute dtype (int8 corner DIFFERENCES can reach ±254 — the
-    cast must happen before Eq. 4), and the scale multiplies ONCE after
-    aggregation — exact, because the scale is shared across rows.
-    Returns (TQ, n) with n = Dv unless sliced."""
-    x0 = jnp.floor(x)
-    y0 = jnp.floor(y)
-    t1 = (x - x0)[..., None]                    # frac along x
-    t0 = (y - y0)[..., None]                    # frac along y
-    x0i = x0.astype(jnp.int32)
-    y0i = y0.astype(jnp.int32)
 
-    def corner(dx, dy):
-        cx = x0i + dx
-        cy = y0i + dy
-        valid = (cx >= 0) & (cx < wl) & (cy >= 0) & (cy < hl)
-        idx = st + jnp.clip(cy, 0, hl - 1) * wl + jnp.clip(cx, 0, wl - 1)
-        if remap is not None:
-            idx = jnp.take(remap, idx.reshape(-1)).reshape(idx.shape)
-        g = jnp.take(v, idx.reshape(-1), axis=0).reshape(idx.shape + (v.shape[-1],))
-        if lanes is not None:
-            g = g[..., lanes[0]:lanes[0] + lanes[1]]
-        if scale is not None:
-            g = g.astype(probs.dtype)
-        return g * valid[..., None]
+def table_words(t: jnp.ndarray) -> jnp.ndarray:
+    """(..., C) table -> (..., C / p) uint32 words, p = word_planes.
 
-    n0 = corner(0, 0)
-    n1 = corner(1, 0)
-    n2 = corner(0, 1)
-    n3 = corner(1, 1)
-    # Eq. 4 — exactly three multiplies by the fractional coordinates:
-    s = n0 + (n2 - n0) * t0 + ((n1 - n0) + (n3 - n2 - n1 + n0) * t0) * t1
-    out = jnp.sum(s * probs[..., None], axis=1)
+    Channel c lives in word c // p at bit offset (32 / p)·(c % p). float16
+    has no shift-only widening, so it is staged as f32 words."""
+    if t.dtype == jnp.float16:
+        t = t.astype(jnp.float32)
+    p = word_planes(t.dtype)
+    if p == 1:
+        return jax.lax.bitcast_convert_type(t, _U32)
+    return jax.lax.bitcast_convert_type(
+        t.reshape(t.shape[:-1] + (t.shape[-1] // p, p)), _U32)
+
+
+def _unpack(words: jnp.ndarray, dtype) -> Tuple[jnp.ndarray, ...]:
+    """uint32 words -> one f32 array per channel plane (exact widening)."""
+    d = jnp.dtype(dtype)
+    f32 = jnp.float32
+    if word_planes(d) == 1:
+        return (jax.lax.bitcast_convert_type(words, f32),)
+    if d == jnp.bfloat16:
+        return (jax.lax.bitcast_convert_type(words << 16, f32),
+                jax.lax.bitcast_convert_type(words & _U32(0xFFFF0000), f32))
+    if d == jnp.int8:
+        i = jax.lax.bitcast_convert_type(words, jnp.int32)
+        return tuple(((i << (24 - 8 * j)) >> 24).astype(f32)
+                     for j in range(4))
+    raise TypeError(f"unsupported MSDA table dtype {d}")
+
+
+def stage_words(vg: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
+    """(B, NG, N_rows, G, Dh) grouped table -> ((B, NG, N_rows, W) uint32
+    words, Dh padded to a whole number of words per head)."""
+    p = word_planes(vg.dtype)
+    dh = vg.shape[-1]
+    dh_p = dh + (-dh) % p
+    if dh_p != dh:
+        vg = jnp.pad(vg, [(0, 0)] * 4 + [(0, dh_p - dh)])
+    b, ng, n, g, _ = vg.shape
+    return table_words(vg.reshape(b, ng, n, g * dh_p)), dh_p
+
+
+def corner_operands(x_px, y_px, start, wl, hl, probs,
+                    remap: Optional[jnp.ndarray] = None):
+    """Per-point corner rows and effective weights, (..., K·4) each.
+
+    Point arrays are (B, ..., K); ``remap`` (B, N_pix) routes pixels to
+    FWP-compact slots (pruned pixels -> the zero sentinel row). Invalid
+    (out-of-level) corners keep a clipped in-range row and weight 0."""
+    idx, wgt, valid = corner_data(x_px, y_px, wl, hl, start)
+    b = idx.shape[0]
+    idx = idx.reshape(idx.shape[:-2] + (-1,))
+    if remap is not None:
+        bidx = jnp.arange(b).reshape((b,) + (1,) * (idx.ndim - 1))
+        idx = remap[bidx, idx]
+    w = (wgt.astype(jnp.float32) * valid.astype(jnp.float32)
+         * probs.astype(jnp.float32)[..., None])
+    return idx.astype(jnp.int32), w.reshape(w.shape[:-2] + (-1,))
+
+
+def vmem_limit(*block_bytes: int) -> int:
+    """Scoped-VMEM limit for a launch whose pipelined blocks take
+    ``block_bytes`` each (double-buffered), with headroom; never below
+    the compiler's 16 MiB default nor above 100 MiB."""
+    need = 2 * sum(block_bytes) + (4 << 20)
+    return int(min(max(need, 16 << 20), 100 << 20))
+
+
+def query_tile(block_q: int, head_pack: int, n_corners: int) -> int:
+    """Query tile: ``block_q`` clipped so one step's corner scalars fit
+    SMEM, a multiple of 8 (the output block's sublane tiling)."""
+    cap = max(8, SMEM_CORNERS // (head_pack * n_corners) // 8 * 8)
+    return max(8, min(-(-block_q // 8) * 8, cap))
+
+
+def fold_rows(words: jnp.ndarray) -> jnp.ndarray:
+    """(..., N_rows, W) words -> (..., N_rows / R, R·W) with R = 128 // W
+    table rows side by side in one 128-lane row when W divides 128: VMEM
+    pads a block's last dim to 128 lanes, so narrow rows are folded
+    instead of padded (the staged block then holds exactly the table's
+    bytes). R = 1 when W >= 128 or W does not divide 128."""
+    n_w = words.shape[-1]
+    fold = _LANES // n_w if n_w < _LANES and _LANES % n_w == 0 else 1
+    if fold == 1:
+        return words
+    n = words.shape[-2]
+    words = _pad_axis(words, words.ndim - 2, (-n) % fold)
+    return words.reshape(words.shape[:-2] + (-1, fold * n_w))
+
+
+def accumulate_tile(idx_ref, w_ref, tab, o_ref, *, tq: int, head_pack: int,
+                    n_corners: int, row_words: int, dtype) -> None:
+    """The shared kernel body: for each of ``tq`` queries and each of the
+    ``head_pack`` heads, sum ``w * table[row]`` over the head's corners.
+
+    idx_ref / w_ref: SMEM (tq·G·M,) corner rows and weights, ordered
+    (query, head, corner); tab: VMEM (..., rows / R, R·row_words) uint32
+    ref of :func:`fold_rows`-folded words; o_ref: VMEM
+    (..., planes, tq, R·row_words) f32 ref whose first ``row_words``
+    lanes receive the result. Leading block dims are size 1 and indexed
+    at 0. Head g owns words [g·wph, (g+1)·wph) of a table row,
+    wph = row_words / G. A corner of folded sub-row s lands in lanes
+    [s·row_words, (s+1)·row_words) of its accumulator; the sub-rows are
+    summed with lane rotations once per query."""
+    lanes = tab.shape[-1]
+    fold = lanes // row_words
+    shift = fold.bit_length() - 1
+    t_lead = (0,) * (len(tab.shape) - 2)
+    o_lead = (0,) * (len(o_ref.shape) - 3)
+    g_n, m_n = head_pack, n_corners
+    n_planes = word_planes(dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    lane_sub = lane // row_words
+    lane_head = (lane % row_words) // (row_words // g_n)
+    step = CORNER_UNROLL if m_n % CORNER_UNROLL == 0 else 1
+    zeros = lambda: tuple(jnp.zeros((1, lanes), jnp.float32)
+                          for _ in range(n_planes))
+
+    def load(e):
+        r = idx_ref[e]
+        x = tab[t_lead + (pl.ds(r >> shift, 1), slice(None))]
+        wgt = w_ref[e]
+        if fold > 1:
+            wgt = jnp.where(lane_sub == (r & (fold - 1)), wgt, 0.0)
+        return wgt, _unpack(x, dtype)
+
+    def per_query(q, carry):
+        base = q * (g_n * m_n)
+        out = zeros()
+        for g in range(g_n):                              # static unroll
+            def corners(i, acc, _e0=base + g * m_n):
+                for u in range(step):
+                    wgt, planes = load(_e0 + i * step + u)
+                    acc = tuple(a + wgt * v for a, v in zip(acc, planes))
+                return acc
+            acc = jax.lax.fori_loop(0, m_n // step, corners, zeros())
+            if g_n == 1:
+                out = acc
+            else:
+                own = lane_head == g
+                out = tuple(o + jnp.where(own, a, 0.0)
+                            for o, a in zip(out, acc))
+        for j in range(n_planes):
+            o = out[j]
+            half = lanes // 2
+            while half >= row_words:        # sum the folded sub-rows
+                o = o + pltpu.roll(o, half, 1)
+                half //= 2
+            o_ref[o_lead + (j, pl.ds(q, 1), slice(None))] = o
+        return carry
+
+    jax.lax.fori_loop(0, tq, per_query, 0)
+
+
+def _pad_axis(a, axis: int, pad: int, value=0):
+    if not pad:
+        return a
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(a, widths, constant_values=value)
+
+
+def unplane(out: jnp.ndarray, head_pack: int, dh_p: int,
+            dh: int) -> jnp.ndarray:
+    """(..., NG, planes, Nq, W) f32 kernel output -> (..., Nq, NG·G, Dh)."""
+    *lead, ng, p, nq, n_w = out.shape
+    nl = len(lead)
+    perm = tuple(range(nl)) + (nl + 2, nl, nl + 3, nl + 1)
+    o = out.transpose(perm).reshape(tuple(lead) + (nq, ng, head_pack, dh_p))
+    return o[..., :dh].reshape(tuple(lead) + (nq, ng * head_pack, dh))
+
+
+def group_heads(a: jnp.ndarray, head_pack: int, nq_p: int,
+                fill=0) -> jnp.ndarray:
+    """(B, ..., Nq, H, K) point operand -> (B, H/G, ..., Nq_p, G, K): the
+    query axis padded to ``nq_p`` with ``fill`` and the heads split into
+    groups, BEFORE the corner expansion — flattening the expanded
+    (..., G, 4K) corners is then a plain reshape, where transposing them
+    costs the TPU compiler tens of seconds at 20k queries."""
+    a = _pad_axis(a, a.ndim - 3, nq_p - a.shape[-3], fill)
+    *lead, nq, h, k = a.shape
+    a = a.reshape(tuple(lead) + (nq, h // head_pack, head_pack, k))
+    n = len(lead)
+    return a.transpose((0, n + 1) + tuple(range(1, n)) + (n, n + 2, n + 3))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "dtype", "head_pack", "dh", "block_q", "name", "interpret"))
+def sample_staged(words: jnp.ndarray, x_px, y_px, start, wl, hl, probs,
+                  remap: Optional[jnp.ndarray], *, dtype, head_pack: int,
+                  dh: int, block_q: int, name: str,
+                  interpret: bool) -> jnp.ndarray:
+    """One launch over grid (B, H/G, query tile, layer), layer innermost.
+
+    words: (B, NG, N_rows, W) uint32 table staged from a ``dtype`` table
+    (:func:`stage_words`); point operands (B, L, Nq, H, K), ``remap``
+    (B, N_pix) or None (:func:`corner_operands`). The table block is
+    indexed by (batch, head-group) only, so it stays resident in VMEM
+    across the whole (query-tile x layer) sweep of one (batch,
+    head-group). Returns (B, L, Nq, H, Dh) f32."""
+    b, ng, _, n_w = words.shape
+    _, n_l, nq, h, k = x_px.shape
+    g = head_pack
+    m = 4 * k
+    p = word_planes(dtype)
+    dh_p = n_w * p // g
+    words = fold_rows(words)
+    n_rows, lanes = words.shape[2:]
+    tq = query_tile(block_q, g, m)
+    nq_p = nq + (-nq) % tq
+    n_t = nq_p // tq
+
+    # padded queries: zero probability, in-range one-pixel level
+    grp = lambda a, fill=0: group_heads(a, g, nq_p, fill)
+    idx, w = corner_operands(grp(x_px), grp(y_px), grp(start), grp(wl, 1),
+                             grp(hl, 1), grp(probs), remap)
+    blk = tq * g * m
+
+    def scalar_block(bi, gi, ti, li):
+        return (((bi * ng + gi) * n_l + li) * n_t + ti,)
+
+    sspec = pl.BlockSpec((blk,), scalar_block, memory_space=pltpu.SMEM)
+    tspec = pl.BlockSpec((1, 1, n_rows, lanes),
+                         lambda bi, gi, ti, li: (bi, gi, 0, 0))
+    ospec = pl.BlockSpec((1, 1, 1, p, tq, lanes),
+                         lambda bi, gi, ti, li: (bi, gi, li, 0, ti, 0))
+
+    def kernel(idx_ref, w_ref, tab_ref, o_ref):
+        accumulate_tile(idx_ref, w_ref, tab_ref, o_ref, tq=tq, head_pack=g,
+                        n_corners=m, row_words=n_w, dtype=dtype)
+
+    out = pl.pallas_call(
+        kernel, grid=(b, ng, n_t, n_l),
+        in_specs=[sspec, sspec, tspec], out_specs=ospec,
+        out_shape=jax.ShapeDtypeStruct((b, ng, n_l, p, nq_p, lanes),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(n_rows * lanes * 4,
+                                        p * tq * lanes * 4)),
+        interpret=interpret, name=name,
+    )(idx.reshape(-1), w.reshape(-1), words)
+    out = out[..., :n_w].transpose(0, 2, 1, 3, 4, 5)  # (B, L, NG, p, Nq, W)
+    return unplane(out, g, dh_p, dh)[:, :, :nq]
+
+
+def _fused(v, x_px, y_px, start, wl, hl, probs, remap, scale, *,
+           head_pack: int, block_q: int, interpret: bool, name: str):
+    b, n_rows, h, dh = v.shape
+    assert h % head_pack == 0, (h, head_pack)
+    g = head_pack
+    vg = v.reshape(b, n_rows, h // g, g, dh).transpose(0, 2, 1, 3, 4)
+    words, _ = stage_words(vg)
+    if remap is not None:
+        name += "_remap"
     if scale is not None:
-        sc = scale if lanes is None else scale[lanes[0]:lanes[0] + lanes[1]]
-        out = out * sc
-    return out
-
-
-def _make_kernel(use_remap: bool, use_scale: bool):
-    """Per-head kernel: one grid step serves one (batch, head) slice."""
-    def kernel(*refs):
-        x_ref, y_ref, st_ref, wl_ref, hl_ref, p_ref = refs[:6]
-        refs = refs[6:]
-        remap = None
-        if use_remap:
-            remap, refs = refs[0][0, :], refs[1:]
-        v_ref = refs[0]
-        scale = refs[1][0, 0, 0, :] if use_scale else None
-        o_ref = refs[-1]
-        o_ref[0, :, 0, :] = _eq4_sample_agg(
-            x_ref[0, :, 0, :], y_ref[0, :, 0, :], st_ref[0, :, 0, :],
-            wl_ref[0, :, 0, :], hl_ref[0, :, 0, :], p_ref[0, :, 0, :],
-            v_ref[0, :, 0, :], remap=remap, scale=scale)
-    return kernel
-
-
-def _make_kernel_packed(head_pack: int, dh: int, use_remap: bool,
-                        use_scale: bool):
-    """Head-packed kernel: one grid step serves ``head_pack`` heads whose
-    value rows are packed side by side into a (N_rows, G·Dh) lane group."""
-    def kernel(*refs):
-        x_ref, y_ref, st_ref, wl_ref, hl_ref, p_ref = refs[:6]
-        refs = refs[6:]
-        remap = None
-        if use_remap:
-            remap, refs = refs[0][0, :], refs[1:]
-        v_ref = refs[0]
-        o_ref = refs[-1]
-        n_rows = v_ref.shape[1]
-        vp = v_ref[0].reshape(n_rows, head_pack * dh)   # packed lane group
-        scale = None
-        if use_scale:                   # (1, 1, G, Dh) -> (G*Dh,)
-            scale = refs[1][0, 0].reshape(head_pack * dh)
-        for g in range(head_pack):                       # static unroll
-            o_ref[0, :, g, :] = _eq4_sample_agg(
-                x_ref[0, :, g, :], y_ref[0, :, g, :], st_ref[0, :, g, :],
-                wl_ref[0, :, g, :], hl_ref[0, :, g, :], p_ref[0, :, g, :],
-                vp, remap=remap, lanes=(g * dh, dh), scale=scale)
-    return kernel
-
-
-def _pad_points(nq, tq, x_px, y_px, probs, start, wl, hl):
-    pad = (-nq) % tq
-    if pad:
-        zf = lambda a: jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        x_px, y_px, probs = zf(x_px), zf(y_px), zf(probs)
-        start = zf(start)
-        wl = jnp.pad(wl, ((0, 0), (0, pad), (0, 0), (0, 0)), constant_values=1)
-        hl = jnp.pad(hl, ((0, 0), (0, pad), (0, 0), (0, 0)), constant_values=1)
-    return pad, x_px, y_px, probs, start, wl, hl
+        name += "_int8"
+    add_l = lambda a: a[:, None]
+    out = sample_staged(words, *map(add_l, (x_px, y_px, start, wl, hl,
+                                            probs)), remap,
+                        dtype=jnp.dtype(v.dtype), head_pack=g, dh=dh,
+                        block_q=block_q, name=name,
+                        interpret=interpret)[:, 0]
+    if scale is not None:
+        # int8 codes aggregate exactly in f32; the per-channel scale is
+        # shared by every row, so it multiplies once after aggregation
+        return (out * scale.astype(jnp.float32)).astype(probs.dtype)
+    return out.astype(v.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
@@ -156,46 +353,14 @@ def msgs_fused_pallas(
     block_q: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    b, n_rows, h, dh = v.shape
-    _, nq, _, k = x_px.shape
-    tq = min(block_q, nq)
-    pad, x_px, y_px, probs, start, wl, hl = _pad_points(
-        nq, tq, x_px, y_px, probs, start, wl, hl)
-    nq_p = nq + pad
-    grid = (b, h, nq_p // tq)
-
-    pt_spec = pl.BlockSpec((1, tq, 1, k), lambda bi, hi, qi: (bi, qi, hi, 0))
-    v_spec = pl.BlockSpec((1, n_rows, 1, dh), lambda bi, hi, qi: (bi, 0, hi, 0))
-    out_spec = pl.BlockSpec((1, tq, 1, dh), lambda bi, hi, qi: (bi, qi, hi, 0))
-    out_dtype = v.dtype if scale is None else probs.dtype
-    out_shape = jax.ShapeDtypeStruct((b, nq_p, h, dh), out_dtype)
-
-    in_specs = [pt_spec] * 6
-    inputs = [x_px, y_px, start, wl, hl, probs]
-    name = "msgs_fused"
-    if remap is not None:
-        in_specs.append(pl.BlockSpec((1, remap.shape[1]),
-                                     lambda bi, hi, qi: (bi, 0)))
-        inputs.append(remap)
-        name += "_remap"
-    in_specs.append(v_spec)
-    inputs.append(v)
-    if scale is not None:
-        in_specs.append(pl.BlockSpec((1, 1, 1, dh),
-                                     lambda bi, hi, qi: (bi, 0, hi, 0)))
-        inputs.append(scale)
-        name += "_int8"
-    out = pl.pallas_call(
-        _make_kernel(use_remap=remap is not None,
-                     use_scale=scale is not None),
-        grid=grid, in_specs=in_specs,
-        out_specs=out_spec, out_shape=out_shape,
-        interpret=interpret, name=name,
-    )(*inputs)
-    return out[:, :nq] if pad else out
+    """Per-head fused MSGS: one head per grid step, (N_rows, Dh) rows."""
+    return _fused(v, x_px, y_px, start, wl, hl, probs, remap, scale,
+                  head_pack=1, block_q=block_q, interpret=interpret,
+                  name="msgs_fused")
 
 
-@functools.partial(jax.jit, static_argnames=("head_pack", "block_q", "interpret"))
+@functools.partial(jax.jit, static_argnames=("head_pack", "block_q",
+                                             "interpret"))
 def msgs_fused_packed_pallas(
     v: jnp.ndarray,                      # (B, N_rows, H, Dh)
     x_px: jnp.ndarray,                   # (B, Nq, H, K)
@@ -211,44 +376,8 @@ def msgs_fused_packed_pallas(
     block_q: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Head-packed fused MSGS: G = head_pack heads share one 128-lane
-    group — grid (B, H/G, Nq/TQ), staged table (N_rows, G·Dh)."""
-    b, n_rows, h, dh = v.shape
-    _, nq, _, k = x_px.shape
-    assert h % head_pack == 0, (h, head_pack)
-    tq = min(block_q, nq)
-    pad, x_px, y_px, probs, start, wl, hl = _pad_points(
-        nq, tq, x_px, y_px, probs, start, wl, hl)
-    nq_p = nq + pad
-    g = head_pack
-    grid = (b, h // g, nq_p // tq)
-
-    pt_spec = pl.BlockSpec((1, tq, g, k), lambda bi, gi, qi: (bi, qi, gi, 0))
-    v_spec = pl.BlockSpec((1, n_rows, g, dh), lambda bi, gi, qi: (bi, 0, gi, 0))
-    out_spec = pl.BlockSpec((1, tq, g, dh), lambda bi, gi, qi: (bi, qi, gi, 0))
-    out_dtype = v.dtype if scale is None else probs.dtype
-    out_shape = jax.ShapeDtypeStruct((b, nq_p, h, dh), out_dtype)
-
-    in_specs = [pt_spec] * 6
-    inputs = [x_px, y_px, start, wl, hl, probs]
-    name = "msgs_fused_packed"
-    if remap is not None:
-        in_specs.append(pl.BlockSpec((1, remap.shape[1]),
-                                     lambda bi, gi, qi: (bi, 0)))
-        inputs.append(remap)
-        name += "_remap"
-    in_specs.append(v_spec)
-    inputs.append(v)
-    if scale is not None:
-        in_specs.append(pl.BlockSpec((1, 1, g, dh),
-                                     lambda bi, gi, qi: (bi, 0, gi, 0)))
-        inputs.append(scale)
-        name += "_int8"
-    kernel = _make_kernel_packed(g, dh, use_remap=remap is not None,
-                                 use_scale=scale is not None)
-    out = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs,
-        out_specs=out_spec, out_shape=out_shape,
-        interpret=interpret, name=name,
-    )(*inputs)
-    return out[:, :nq] if pad else out
+    """Head-packed fused MSGS: G = head_pack heads share one staged row
+    (grid (B, H/G, Nq/TQ), staged table (N_rows, G·Dh))."""
+    return _fused(v, x_px, y_px, start, wl, hl, probs, remap, scale,
+                  head_pack=head_pack, block_q=block_q, interpret=interpret,
+                  name="msgs_fused_packed")

@@ -10,24 +10,26 @@ C5 at the launch level): ONE ``pallas_call`` whose grid spans
 
     (batch x head-group x query-tile)
 
-with the sampled-level axis unrolled *inside* each grid step. Every step
-stages all L range-narrowed level windows into VMEM at once — each level
-gets its own statically-sized BlockSpec window, so the big level's
-window never inflates the small levels' staging (a level axis in the
-grid would force one uniform window extent on every level). The L
-partial sums accumulate in registers and the output block is written
-once — cross-level aggregation is fused in-kernel instead of
+with all L sampled levels served inside each grid step. At a tile's
+first step the L range-narrowed level windows are copied by DMA into one
+VMEM buffer, each at its own static extent, so the big level's window
+never inflates the small levels' staging (a level axis in the grid would
+force one uniform window extent on every level). The L levels' corners
+accumulate into one register accumulator and the output block is
+written once — cross-level aggregation is fused in-kernel instead of
 materialized as L HBM-sized accumulators, and the co-resident level
 windows are the VMEM analogue of DEFA's inter-level parallel PE groups.
+The corner rows are computed outside the kernel and made window-local
+(a corner outside its level's window contributes nothing); the kernel
+loads them as in the fused kernel (``msgs_fused.accumulate_tile``).
 The kernel is **FWP-compact-native**: when the value table is compacted,
 each level window is a *slot* window of the compact table (slots are
 raster-ordered per level, so a pixel window maps to one contiguous slot
 range located by ``searchsorted(keep_idx, window_start)`` and bounded
-statically by ``min(window_pixels, level_capacity)``), and the corner
-gather goes through a windowed slice of the ``pix2slot`` indirection —
-the densified (B, N_in, H, Dh) table is never built. Dynamic window
-starts ride in as scalar-prefetch arguments so the BlockSpec index maps
-can DMA the right slab.
+statically by ``min(window_pixels, level_capacity)``), and corners are
+routed to slots through ``pix2slot`` outside the kernel — the densified
+(B, N_in, H, Dh) table is never built. The window starts ride in as a
+scalar-prefetch argument that addresses the DMAs.
 
 (The first generation — ``msgs_windowed_pallas``, one launch per
 (query-level x sampled-level) pair — served its one release as the
@@ -55,6 +57,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.msgs_fused import (_pad_axis, accumulate_tile,
+                                     corner_operands, fold_rows, query_tile,
+                                     stage_words, unplane, vmem_limit,
+                                     word_planes)
 
 
 # ==========================================================================
@@ -192,125 +199,16 @@ def unpack_queries(geo: WindowGeometry, arr: jnp.ndarray) -> jnp.ndarray:
 # Multi-scale-parallel windowed kernel (single launch, fused aggregation)
 # ==========================================================================
 
-def _make_msp_kernel(geo: WindowGeometry, w_rows_v: Tuple[int, ...],
-                     head_pack: int, dh: int, use_remap: bool,
-                     use_scale: bool = False):
-    """Kernel body for grid (B, H/G, T); sampled levels unrolled in-body.
-
-    Refs (after the scalar-prefetch window starts): x, y, level, probs
-    point blocks (1, TQ, G, K); per level an optional remap window
-    (1, w_pix_levels[l]) and a value window (1, w_rows_v[l], G, Dh);
-    with ``use_scale`` the group's (1, 1, G, Dh) f32 dequant scale block;
-    output block (1, TQ, G, Dh). All L level windows are resident in the
-    same grid step — the VMEM analogue of DEFA's inter-level parallel PE
-    groups — and their partial sums accumulate in registers, so level
-    aggregation is fused with no HBM round-trip and no output revisiting.
-    Int8 windows gather 1-byte codes, cast to the accumulator dtype
-    before Eq. 4 (corner differences overflow int8), and the scale
-    multiplies the accumulated sum ONCE at the end — exact, because the
-    scale is shared across rows."""
-    n_l = len(geo.level_shapes)
-
-    def kernel(*refs):
-        if use_remap:
-            vstart_ref, pstart_ref = refs[0], refs[1]
-            x_ref, y_ref, lvl_ref, p_ref = refs[2:6]
-            r_refs = refs[6:6 + n_l]
-            v_refs = refs[6 + n_l:6 + 2 * n_l]
-        else:
-            vstart_ref = refs[0]
-            x_ref, y_ref, lvl_ref, p_ref = refs[1:5]
-            v_refs = refs[5:5 + n_l]
-        s_ref = refs[-2] if use_scale else None
-        o_ref = refs[-1]
-        b = pl.program_id(0)
-        t = pl.program_id(2)
-
-        x = x_ref[0]                                     # (TQ, G, K)
-        y = y_ref[0]
-        lvlp = lvl_ref[0]
-        probs = p_ref[0]
-        gid = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-
-        x0 = jnp.floor(x)
-        y0 = jnp.floor(y)
-        t1 = (x - x0)[..., None]
-        t0 = (y - y0)[..., None]
-        x0i = x0.astype(jnp.int32)
-        y0i = y0.astype(jnp.int32)
-
-        acc = jnp.zeros(x.shape[:2] + (dh,), o_ref.dtype)
-        for l, (h_l, w_l) in enumerate(geo.level_shapes):
-            st_l = geo.level_starts[l]
-            wv = w_rows_v[l]
-            wp = geo.w_pix_levels[l]
-            # The whole head group is processed vectorized: the packed
-            # level window is viewed as (wv * G, Dh) and each head's
-            # corner gather addresses row*G + head, so one flat take
-            # serves all G heads with no per-head lane slicing.
-            v3 = v_refs[l][0].reshape(wv * head_pack, dh)
-            on = lvlp == l                               # point on level l
-            if use_remap:
-                r2 = r_refs[l][0]
-                s_lo = vstart_ref[b, t, l]
-                p_lo = pstart_ref[t, l]
-            else:
-                s_lo = vstart_ref[t, l]
-
-            def corner(dx, dy):
-                cx = x0i + dx
-                cy = y0i + dy
-                valid = on & (cx >= 0) & (cx < w_l) & (cy >= 0) & (cy < h_l)
-                pix = (st_l + jnp.clip(cy, 0, h_l - 1) * w_l
-                       + jnp.clip(cx, 0, w_l - 1))
-                if use_remap:
-                    lpix = pix - p_lo
-                    valid &= (lpix >= 0) & (lpix < wp)
-                    lpix = jnp.clip(lpix, 0, wp - 1)
-                    slot = jnp.take(r2, lpix.reshape(-1)).reshape(lpix.shape)
-                    lrow = slot - s_lo                   # slot-window local
-                else:
-                    lrow = pix - s_lo                    # pixel-window local
-                valid &= (lrow >= 0) & (lrow < wv)
-                idx = jnp.clip(lrow, 0, wv - 1) * head_pack + gid
-                gat = jnp.take(v3, idx.reshape(-1), axis=0).reshape(
-                    idx.shape + (dh,))
-                if use_scale:
-                    gat = gat.astype(o_ref.dtype)
-                return gat * valid[..., None]
-
-            n0 = corner(0, 0)
-            n1 = corner(1, 0)
-            n2 = corner(0, 1)
-            n3 = corner(1, 1)
-            # Eq. 4 — three multiplies by the fractional coordinates:
-            s = (n0 + (n2 - n0) * t0
-                 + ((n1 - n0) + (n3 - n2 - n1 + n0) * t0) * t1)
-            acc += jnp.sum(s * probs[..., None], axis=2)
-        if use_scale:
-            acc = acc * s_ref[0, 0]              # (G, Dh) broadcasts
-        o_ref[0] = acc
-    return kernel
-
-
-def _v_index(l: int, g: int, use_remap: bool):
-    if use_remap:
-        return lambda bi, gi, ti, vs, ps: (bi, vs[bi, ti, l], gi * g, 0)
-    return lambda bi, gi, ti, vs: (bi, vs[ti, l], gi * g, 0)
-
-
-def _r_index(l: int):
-    return lambda bi, gi, ti, vs, ps: (bi, ps[ti, l])
-
-
-def _elem_spec(shape: Tuple[int, ...], index_map) -> pl.BlockSpec:
-    """Element-offset window BlockSpec across jax versions: every dim is
-    element-indexed (the index maps return element offsets for all dims,
-    e.g. ``gi * g`` for the head axis) — ``pl.Element`` per dim on
-    jax >= 0.5, ``indexing_mode=pl.Unblocked()`` before."""
-    if hasattr(pl, "Element"):           # jax >= 0.5 spelling
-        return pl.BlockSpec(tuple(pl.Element(s) for s in shape), index_map)
-    return pl.BlockSpec(shape, index_map, indexing_mode=pl.Unblocked())
+def _window_plan(geo: WindowGeometry, w_rows_v: Tuple[int, ...],
+                 fold: int) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """Per-level staged window extents and their offsets in the one VMEM
+    window buffer, both in folded rows (:func:`fold_rows`) and multiples
+    of 8: a window starts on an 8-row tile boundary at or below its first
+    row, so it holds up to ``8·fold - 1`` rows of lead-in on top of the
+    ``w_rows_v[l]`` rows it must cover."""
+    ext = tuple(-(-(8 + -(-w // fold)) // 8) * 8 for w in w_rows_v)
+    off = tuple(int(o) for o in np.cumsum((0,) + ext[:-1]))
+    return ext, off
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -334,6 +232,13 @@ def msgs_windowed_msp_pallas(
 ) -> jnp.ndarray:
     """Single-launch multi-scale-parallel windowed MSGS + fused aggregation.
 
+    Grid (B, H/G, tile, sub-tile). At the first sub-tile of a tile the L
+    level windows of the (batch, head-group) table are copied by DMA into
+    one VMEM buffer, where they stay for the tile's sub-tiles; the corner
+    loads then run as in the fused kernel (:func:`accumulate_tile`), with
+    corner rows made window-local outside the kernel. A corner outside its
+    level's window contributes nothing.
+
     Queries must be raster-ordered encoder queries (Nq == N_in). Returns
     (B, Nq, H, Dh). ``remap``/``keep_idx``/``caps`` together enable the
     FWP-compact-native path (v is the compacted table + sentinel row)."""
@@ -343,15 +248,13 @@ def msgs_windowed_msp_pallas(
     use_remap = remap is not None
     assert h % head_pack == 0, (h, head_pack)
     g = head_pack
-    n_groups = h // g
+    ng = h // g
+    m = 4 * k
 
     geo = window_geometry(level_shapes, ranges, tile_q)
     assert nq == geo.n_in, (nq, geo.n_in)
     n_l = len(level_shapes)
-
-    pack = lambda a, fill=0: repack_queries(geo, a, fill=fill)
-    x_px, y_px, probs = pack(x_px), pack(y_px), pack(probs)
-    lvl_of_pt = pack(lvl_of_pt, -1)          # padding matches no level
+    n_t = geo.n_tiles
 
     if use_remap:
         # Window of the COMPACT table: first slot at-or-after the pixel
@@ -363,49 +266,110 @@ def msgs_windowed_msp_pallas(
             geo.slot_windows(caps) if caps is not None else geo.w_pix_levels))
         pix_lo = jnp.asarray(geo.pix_lo.reshape(-1), jnp.int32)
         vstart = jax.vmap(lambda ki: jnp.searchsorted(ki, pix_lo))(keep_idx)
-        vstart = vstart.reshape(b, geo.n_tiles, n_l)
+        vstart = vstart.reshape(b, n_t, n_l)
         hi = jnp.asarray([n_rows - wv for wv in w_rows_v], jnp.int32)
         vstart = jnp.clip(vstart, 0, hi[None, None, :]).astype(jnp.int32)
-        pstart = jnp.asarray(geo.pstart, jnp.int32)
-        scalars = (vstart, pstart)
     else:
         w_rows_v = geo.w_pix_levels
-        vstart = jnp.asarray(geo.pstart, jnp.int32)      # pixel == row space
-        scalars = (vstart,)
+        vstart = jnp.broadcast_to(jnp.asarray(geo.pstart, jnp.int32),
+                                  (b, n_t, n_l))  # pixel == row space
 
-    grid = (b, n_groups, geo.n_tiles)
-    pt = pl.BlockSpec((1, geo.tile_q, g, k),
-                      lambda bi, gi, ti, *s: (bi, ti, gi, 0))
-    v_specs = [_elem_spec((1, w_rows_v[l], g, dh), _v_index(l, g, use_remap))
-               for l in range(n_l)]
-    if use_remap:
-        r_specs = [_elem_spec((1, geo.w_pix_levels[l]), _r_index(l))
-                   for l in range(n_l)]
-        in_specs = [pt, pt, pt, pt] + r_specs + v_specs
-        inputs = ((x_px, y_px, lvl_of_pt, probs) + (remap,) * n_l
-                  + (v,) * n_l)
-    else:
-        in_specs = [pt, pt, pt, pt] + v_specs
-        inputs = (x_px, y_px, lvl_of_pt, probs) + (v,) * n_l
-    name = "msgs_windowed_msp"
-    if scale is not None:
-        in_specs = in_specs + [pl.BlockSpec(
-            (1, 1, g, dh), lambda bi, gi, ti, *s: (bi, gi, 0, 0))]
-        inputs = inputs + (scale,)
-        name += "_int8"
-    out_spec = pl.BlockSpec((1, geo.tile_q, g, dh),
-                            lambda bi, gi, ti, *s: (bi, ti, gi, 0))
-    out_dtype = v.dtype if scale is None else probs.dtype
+    vg = v.reshape(b, n_rows, ng, g, dh).transpose(0, 2, 1, 3, 4)
+    words, dh_p = stage_words(vg)                    # (B, NG, N_rows, W)
+    n_w = words.shape[-1]
+    tab = fold_rows(words)
+    lanes = tab.shape[-1]
+    fold = lanes // n_w
+    ext, off = _window_plan(geo, w_rows_v, fold)
+    n_f = tab.shape[2] + (-tab.shape[2]) % 8
+    n_f = max(n_f, max(ext))
+    tab = _pad_axis(tab, 2, n_f - tab.shape[2])
+    # window starts in folded rows: 8-aligned, clipped into the table
+    ext_a = jnp.asarray(ext, jnp.int32)
+    fstart = jnp.minimum((vstart // fold) // 8 * 8, n_f - ext_a)
 
-    kernel = _make_msp_kernel(geo, w_rows_v, g, dh, use_remap,
-                              use_scale=scale is not None)
+    # corner rows and weights outside the kernel, made window-local
+    pack = lambda a, fill=0: repack_queries(geo, a, fill=fill)
+    lvl = pack(lvl_of_pt, -1)                 # padding matches no level
+    lvl_c = jnp.clip(lvl, 0, n_l - 1)
+    level_arr = lambda vals: jnp.asarray(np.asarray(vals, np.int32))[lvl_c]
+    idx, w = corner_operands(
+        pack(x_px), pack(y_px), level_arr(geo.level_starts),
+        level_arr([w_ for _, w_ in level_shapes]),
+        level_arr([h_ for h_, _ in level_shapes]), pack(probs),
+        remap)                                # (B, Nq_p, H, M)
+    lvl4 = jnp.repeat(lvl, 4, axis=-1)
+    lvl4c = jnp.clip(lvl4, 0, n_l - 1)
+    tile_of = jnp.asarray(np.arange(geo.nq_padded) // geo.tile_q, np.int32)
+    fs = fstart[jnp.arange(b)[:, None, None, None],
+                tile_of[None, :, None, None], lvl4c]
+    span = ext_a[lvl4c] * fold
+    local = idx - fs * fold
+    inside = (lvl4 >= 0) & (local >= 0) & (local < span)
+    idx = jnp.asarray(off, jnp.int32)[lvl4c] * fold \
+        + jnp.clip(local, 0, span - 1)
+    w = jnp.where(inside, w, 0.0)
+
+    tq = math.gcd(geo.tile_q, query_tile(geo.tile_q, g, m))
+    n_s = geo.tile_q // tq
+
+    def lay(a):      # (B, Nq_p, H, M) -> flat (B, NG, Nq_p, G, M)
+        return a.reshape(b, geo.nq_padded, ng, g, m).transpose(
+            0, 2, 1, 3, 4).reshape(-1)
+
+    blk = tq * g * m
+    n_sub = geo.nq_padded // tq
+
+    def corner_block(bi, gi, ti, si, fs_ref):
+        return ((bi * ng + gi) * n_sub + ti * n_s + si,)
+
+    cspec = pl.BlockSpec((blk,), corner_block, memory_space=pltpu.SMEM)
+    ospec = pl.BlockSpec(
+        (1, 1, word_planes(v.dtype), tq, lanes),
+        lambda bi, gi, ti, si, fs_ref: (bi, gi, 0, ti * n_s + si, 0))
+    p = word_planes(v.dtype)
+
+    def kernel(fs_ref, idx_ref, w_ref, tab_hbm, o_ref, win, sems):
+        bi, gi, ti = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+        @pl.when(pl.program_id(3) == 0)
+        def _stage():
+            copies = []
+            for l in range(n_l):
+                r0 = pl.multiple_of(fs_ref[(bi * n_t + ti) * n_l + l], 8)
+                cp = pltpu.make_async_copy(
+                    tab_hbm.at[bi, gi, pl.ds(r0, ext[l])],
+                    win.at[pl.ds(off[l], ext[l])], sems.at[l])
+                cp.start()
+                copies.append(cp)
+            for cp in copies:
+                cp.wait()
+
+        accumulate_tile(idx_ref, w_ref, win, o_ref, tq=tq, head_pack=g,
+                        n_corners=m, row_words=n_w, dtype=v.dtype)
+
+    name = "msgs_windowed_msp" + ("_int8" if scale is not None else "")
+    win_rows = sum(ext)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars), grid=grid,
-            in_specs=in_specs, out_specs=out_spec),
-        out_shape=jax.ShapeDtypeStruct((b, geo.nq_padded, h, dh), out_dtype),
+            num_scalar_prefetch=1, grid=(b, ng, n_t, n_s),
+            in_specs=[cspec, cspec, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=ospec,
+            scratch_shapes=[pltpu.VMEM((win_rows, lanes), jnp.uint32),
+                            pltpu.SemaphoreType.DMA((n_l,))]),
+        out_shape=jax.ShapeDtypeStruct((b, ng, p, geo.nq_padded, lanes),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(win_rows * lanes * 2,
+                                        p * tq * lanes * 4)),
         interpret=interpret, name=name,
-    )(*scalars, *inputs)
-    return unpack_queries(geo, out)
-
+    )(fstart.reshape(-1), lay(idx), lay(w), tab)
+    out = unplane(out[..., :n_w], g, dh_p, dh)      # (B, Nq_p, H, Dh)
+    out = unpack_queries(geo, out)
+    if scale is not None:
+        # int8 codes aggregate exactly in f32; the per-channel scale is
+        # shared by every row, so it multiplies once after aggregation
+        s = scale.reshape(b, 1, h, dh).astype(jnp.float32)
+        return (out * s).astype(probs.dtype)
+    return out.astype(v.dtype)

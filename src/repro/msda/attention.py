@@ -90,7 +90,8 @@ def msda_attention_cached(
     # artifacts (pallas_decode's pre-staged table) find them there,
     # everyone else ignores it
     backend = backend_registry.get_backend(plan.backend)
-    out_h = backend(plan, cache.v, pts, sel.probs, cache=cache)
+    out_h = backend(plan, cache.v, pts, sel.probs,
+                    cache=cache).astype(query.dtype)
 
     out = jnp.einsum("bnhk,hkd->bnd", out_h, wq(params["out_w"])) \
         + params["out_b"]

@@ -461,19 +461,17 @@ _ENSURE_TRIED = False
 def ensure_applied(cache_path: Optional[str] = None) -> Optional[dict]:
     """Load-only startup hook for the serve engines: apply the persisted
     per-platform entry once per process when none is applied yet. Never
-    measures (startup must stay fast), never raises (a broken table must
-    not take serving down) — at worst the static formulas stand."""
+    measures (startup must stay fast). An unreadable or malformed table
+    warns and leaves the static formulas in effect (:func:`load_table`);
+    any other error propagates."""
     global _ENSURE_TRIED
     if plan_lib.tuned_entry() is not None:
         return plan_lib.tuned_entry()
     if _ENSURE_TRIED:
         return None
     _ENSURE_TRIED = True
-    try:
-        return plan_autotune(measure=False, cache_path=cache_path,
-                             warn_missing=False)
-    except Exception:                     # noqa: BLE001 - serving shield
-        return None
+    return plan_autotune(measure=False, cache_path=cache_path,
+                         warn_missing=False)
 
 
 # --------------------------------------------------------------------------

@@ -24,12 +24,12 @@ backends that don't consume it ignore it.
                                group.
   * ``pallas_windowed``      — multi-scale-parallel windowed kernel
                                (C3+C5+C7): ONE launch whose grid spans
-                               (B x head-group x query-tile x sampled
-                               level), staging only each level's
-                               range-narrowed window and accumulating
-                               cross-level partials in-kernel. Samples the
-                               FWP-compacted table directly through the
-                               pix2slot indirection — never densifies.
+                               (B x head-group x query-tile), staging
+                               only each level's range-narrowed window
+                               and accumulating all levels in-kernel.
+                               Samples the FWP-compacted table directly
+                               through the pix2slot indirection — never
+                               densifies.
                                Needs raster-ordered encoder queries
                                (Nq == N_in) and range-narrowing — no
                                decode-shaped launch.
@@ -182,15 +182,14 @@ def pallas_windowed(plan, v: jnp.ndarray, pts: SamplingPoints,
                     probs: jnp.ndarray, cache=None) -> jnp.ndarray:
     """One Pallas launch across all levels (multi-scale parallelism).
 
-    The grid spans (B x head-group x query-tile x sampled-level) with the
-    level axis innermost: each step stages only that level's
-    range-narrowed window and the partial sums accumulate into the output
-    block in-kernel, so level aggregation is fused instead of materialized
-    as L HBM-sized accumulators. Under FWP-compact the window is a slot
-    window of the compacted table addressed through ``pix2slot`` — the
-    dense (B, N_in, H, Dh) table is never built. Off-level points ride
-    along masked by the in-kernel ``lvl_of_pt == level`` test, which keeps
-    PAP-topk dynamic point-to-level assignment supported."""
+    The grid spans (B x head-group x query-tile); each tile stages all L
+    levels' range-narrowed windows and accumulates every level's corners
+    in-kernel, so level aggregation is fused instead of materialized as L
+    HBM-sized accumulators. Under FWP-compact the window is a slot window
+    of the compacted table addressed through ``pix2slot`` — the dense
+    (B, N_in, H, Dh) table is never built. Each point's corners go to its
+    own level's window, which keeps PAP-topk dynamic point-to-level
+    assignment supported."""
     from repro.core import fwp as fwp_lib
     from repro.kernels import ops as kernel_ops
     cfg = plan.cfg

@@ -231,6 +231,7 @@ def update_value_cache_rows(params: dict, plan, cache: MSDAValueCache,
                             x_flat: jnp.ndarray, slot_idx: jnp.ndarray,
                             act_scale: Optional[jnp.ndarray] = None,
                             keep_mask: Optional[jnp.ndarray] = None,
+                            refresh: Optional[jnp.ndarray] = None,
                             ) -> Tuple[MSDAValueCache, int]:
     """In-place (functional) tile update of a built value cache.
 
@@ -245,7 +246,8 @@ def update_value_cache_rows(params: dict, plan, cache: MSDAValueCache,
     actually moved — ``U`` rows under the plan's lane layout, with NO
     pix2slot restage — the unit the streaming rebuild-vs-incremental
     comparison is measured in (vs ``cache.table_bytes`` for a full
-    build)."""
+    build). ``refresh`` (B, U) bool, when given, marks the rows to
+    re-project; the others are written back unchanged, bit for bit."""
     cfg = plan.cfg
     u = slot_idx.shape[1]
     if cache.keep_idx is not None:                   # compact: slot -> pixel
@@ -259,6 +261,10 @@ def update_value_cache_rows(params: dict, plan, cache: MSDAValueCache,
         # cache's FROZEN per-channel scale and scatter the codes — the
         # dense f32 table is never materialized mid-stream.
         rows = quantize_table_rows(rows, cache.scale)
+    if refresh is not None:
+        bidx = jnp.arange(cache.v.shape[0])[:, None]
+        rows = jnp.where(refresh[..., None, None], rows,
+                         cache.v[bidx, slot_idx])
     v = scatter_table_rows(cache.v, slot_idx, rows)
     staged = cache.staged
     if staged is not None:

@@ -125,10 +125,13 @@ def generate_points(params: dict, cfg, query: jnp.ndarray,
     wl = jnp.take(ws, lvl_of_pt)
     hl = jnp.take(hs, lvl_of_pt)
     st = jnp.take(starts, lvl_of_pt)
-    wl_f = wl.astype(query.dtype)
-    hl_f = hl.astype(query.dtype)
-    x_px = ref_points[:, :, None, None, 0] * wl_f + offs_k[..., 0] - 0.5
-    y_px = ref_points[:, :, None, None, 1] * hl_f + offs_k[..., 1] - 0.5
+    # float32 coordinates whatever the compute dtype: in bf16 a pixel
+    # coordinate on a 128-wide level is off by up to half a pixel
+    f32 = jnp.float32
+    refs = ref_points.astype(f32)
+    offs = offs_k.astype(f32)
+    x_px = refs[:, :, None, None, 0] * wl.astype(f32) + offs[..., 0] - 0.5
+    y_px = refs[:, :, None, None, 1] * hl.astype(f32) + offs[..., 1] - 0.5
     pts = SamplingPoints(x_px=x_px, y_px=y_px, start=st, wl=wl, hl=hl,
                          lvl_of_pt=lvl_of_pt, pix2slot=pix2slot,
                          keep_idx=keep_idx)

@@ -50,6 +50,7 @@ class DetrRequest:
     rid: int
     image: np.ndarray                     # (3, H, W) float32, H/W <= bucket
     # filled by the engine:
+    cls_logits: Optional[np.ndarray] = None   # (Nq, C+1) as served
     cls_probs: Optional[np.ndarray] = None    # (Nq, C+1) softmax
     boxes: Optional[np.ndarray] = None        # (Nq, 4) cxcywh
     detections: Optional[dict] = None         # top-k decode (postproc stage)
@@ -85,7 +86,7 @@ class DetrServeEngine:
         from repro.msda.autotune import ensure_applied
         ensure_applied()   # load-only: the committed/measured plan table,
         #   so bucket derivation below sees the tuned budgets (never
-        #   raises, never times anything)
+        #   times anything)
         self.cfg = cfg
         self.params = params
         self.max_batch = int(max_batch)
@@ -127,6 +128,7 @@ class DetrServeEngine:
         self.rejected: list[DetrRequest] = []
         self._lock = threading.Lock()
         self._compiled = {}
+        self.compile_seconds: dict[int, float] = {}
         for b in self.buckets:
             # compile-count spy: the increment executes at TRACE time
             # only, so after the AOT warmup below it must never move
@@ -137,8 +139,10 @@ class DetrServeEngine:
                 return detector_apply(p, _cfg, img, backend=self.backend)
             spec = jax.ShapeDtypeStruct(
                 (self.max_batch, 3, b.resolution, b.resolution), jnp.float32)
+            t0 = time.perf_counter()
             self._compiled[b.resolution] = \
                 jax.jit(fwd).lower(self.params, spec).compile()
+            self.compile_seconds[b.resolution] = time.perf_counter() - t0
             self.obs.tracer.event("plan", engine="DetrServeEngine",
                                   bucket=b.resolution,
                                   plan=b.plan.snapshot())
@@ -237,13 +241,15 @@ class DetrServeEngine:
     def _complete(self, item) -> None:
         batch, cls_logits, boxes, dev_span = item
         tr = self.obs.tracer
-        probs = softmax_np(np.asarray(cls_logits))
+        cls_logits = np.asarray(cls_logits)
+        probs = softmax_np(cls_logits)
         boxes = np.asarray(boxes)
         if dev_span:
             sp = tr.end(dev_span)    # after np.asarray: transfer included
             self._m_span.observe(sp.duration_s, span="device")
         post_span = tr.start("postproc", n=len(batch))
         for i, req in enumerate(batch):
+            req.cls_logits = cls_logits[i]
             req.cls_probs = probs[i]
             req.boxes = boxes[i]
             req.detections = topk_detections(probs[i], boxes[i], self.topk)

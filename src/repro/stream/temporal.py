@@ -284,11 +284,13 @@ class TemporalCacheManager:
 
     def _update_impl(self, params, x_new, x_ref, v, staged, keep_idx,
                      keep_mask, changed, slot_dirty, act_scale, table_scale):
-        # dirty slots first; clean fillers re-project unchanged (or
-        # sub-threshold-drifted) pixels, which is harmless by construction
+        # dirty slots first; the clean fillers that pad the static budget
+        # are written back unchanged, so a clean row keeps exactly the
+        # value of its last re-projection
         _, idx_u = jax.lax.top_k(slot_dirty.astype(jnp.float32),
                                  self.update_rows)
         idx_u = jnp.sort(idx_u, axis=1)
+        refresh = jnp.take_along_axis(slot_dirty, idx_u, axis=1)
         # the ONE row-update path (cache.py): project + scatter into the
         # table and its decode staging. The temp cache just pairs the
         # traced arrays with this manager's static metadata. ``table_scale``
@@ -302,7 +304,8 @@ class TemporalCacheManager:
                              scale=table_scale)
         upd, _ = update_value_cache_rows(params, self.plan, tmp, x_new,
                                          idx_u, act_scale=act_scale,
-                                         keep_mask=keep_mask)
+                                         keep_mask=keep_mask,
+                                         refresh=refresh)
         pix_changed = changed[jnp.arange(x_new.shape[0])[:, None],
                               jnp.asarray(self.geo.tile_of_pixel)[None]]
         x_ref = jnp.where(pix_changed[..., None], self._probe(x_new), x_ref)
@@ -379,8 +382,9 @@ class TemporalCacheManager:
     def _transition_levels(self) -> Optional[Tuple[int, ...]]:
         """Which levels' keep geometry changed vs the cache's, or None
         when a partial restage is not applicable (not compact, no
-        geometry to compare, nothing changed, or EVERY level changed —
-        then a full rebuild moves the same bytes with one build)."""
+        geometry to compare, nothing changed, EVERY level changed, or
+        the restage plus the frame's incremental update would stage at
+        least a full rebuild's bytes — then one build is cheaper)."""
         new, old = self.fwp, self._cache_fwp
         if not self._compact or new is None or old is None \
                 or new.keep_idx is None or old.keep_idx is None:
@@ -395,7 +399,24 @@ class TemporalCacheManager:
                 changed.append(li)
         if not changed or len(changed) == len(self.plan.level_shapes):
             return None
+        # the frame runs the incremental update after the restage, so the
+        # partial path pays only when both stage fewer bytes than one
+        # full rebuild
+        if self._partial_bytes(changed) + self._incr_bytes \
+                >= self._full_bytes:
+            return None
         return tuple(changed)
+
+    def _partial_bytes(self, levels) -> int:
+        """Bytes a restage of ``levels`` stages: their slot rows under
+        the plan's lane layout plus their share of the pix2slot
+        indirection."""
+        rows = sum(self._slot_offs[l + 1] - self._slot_offs[l]
+                   for l in levels)
+        pix = sum(h * w for l, (h, w) in enumerate(self.plan.level_shapes)
+                  if l in levels)
+        return self.plan.table_bytes_for_rows(
+            rows, with_indirection=False) + pix * 4
 
     def _partial_restage(self, x_new: jnp.ndarray,
                          levels: Tuple[int, ...]) -> int:
@@ -426,17 +447,14 @@ class TemporalCacheManager:
             pix2slot=self.fwp.pix2slot)
         x_ref = self.x_ref
         probe = self._probe(x_new)
-        pix_restaged = 0
         for l in levels:
             h, w = self.plan.level_shapes[l]
             p0 = self._pix_starts[l]
             x_ref = x_ref.at[:, p0:p0 + h * w].set(probe[:, p0:p0 + h * w])
-            pix_restaged += h * w
         self.x_ref = x_ref
         self._cache_fwp = self.fwp
         self._geometry_stale = False
-        return self.plan.table_bytes_for_rows(
-            len(slot_np), with_indirection=False) + pix_restaged * 4
+        return self._partial_bytes(levels)
 
     def permute_slots(self, perm) -> None:
         """Reorder the batch (session) slots of every per-slot array.

@@ -74,7 +74,6 @@ def test_compressed_psum_shard_map():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.optim.compress import compressed_psum
 
     mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4), ("pod", "data"))
@@ -84,7 +83,7 @@ def test_compressed_psum_shard_map():
         out, new_res = compressed_psum(g_local, "pod", bits=8, residual=res)
         return out, new_res
 
-    fm = shard_map(f, mesh=mesh,
+    fm = jax.shard_map(f, mesh=mesh,
                    in_specs=(P(("pod", "data")), P(("pod", "data"))),
                    out_specs=(P(("pod", "data")), P(("pod", "data"))))
     res = jnp.zeros_like(g)

@@ -434,6 +434,41 @@ def test_int8_table_matches_f32_within_quant_tol(backend, fwp, packed):
 
 
 # --------------------------------------------------------------------------
+# bf16 value table: every Pallas backend vs jnp_gather in bf16
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("packed", (False, True), ids=("padlane", "packed"))
+@pytest.mark.parametrize("backend", ("pallas_fused", "pallas_windowed",
+                                     "pallas_decode"))
+def test_bf16_table_matches_jnp(backend, packed):
+    """A bf16 model (bf16 table, queries and weights — the precision of
+    ``configs/detr_family.py``) runs on every Pallas backend and agrees
+    with ``jnp_gather`` on the same bf16 inputs. Both paths accumulate
+    the corners in f32 and round the per-head samples to bf16 once, so
+    they differ by about one bf16 rounding (2^-8 relative) through the
+    bf16 output projection."""
+    if backend == "pallas_decode":
+        cfg, params, q2, refs2, x, _ = _decode_setup(packed, "off")
+        plan_kw = dict(n_queries=N_DEC_Q, n_consumers=6)
+    else:
+        cfg, params, q2, refs2, x = _combo_setup(packed)
+        plan_kw = dict(block_q=64)
+    bf = jnp.bfloat16
+    cfg = dataclasses.replace(cfg, dtype=bf, table_dtype="bfloat16")
+    params = jax.tree.map(lambda a: a.astype(bf), params)
+    q2, refs2, x = q2.astype(bf), refs2.astype(bf), x.astype(bf)
+    outs = {}
+    for be in ("jnp_gather", backend):
+        plan = msda.make_plan(cfg, LEVELS, backend=be, **plan_kw)
+        assert plan.table_dtype == "bfloat16"
+        out, _ = msda.msda_attention(params, plan, q2, refs2, x)
+        assert out.dtype == bf
+        outs[be] = np.asarray(out.astype(jnp.float32))
+    np.testing.assert_allclose(outs[backend], outs["jnp_gather"],
+                               rtol=1e-2, atol=1e-2)
+
+
+# --------------------------------------------------------------------------
 # plan resolution
 # --------------------------------------------------------------------------
 

@@ -347,15 +347,19 @@ def test_partial_restage_matches_scratch_build(backend):
     values, staged decode table AND swapped geometry — is bit-identical
     to a from-scratch build of the frame under the new keep set."""
     cfg = _cfg()
-    mgr, plan = _mgr(cfg, StreamConfig(tile_rows=2, delta_threshold=0.0,
-                                       update_frac=1.0), backend=backend)
+    # a small update budget, so the restage plus the incremental update
+    # stage fewer bytes than a rebuild; the frame moves only level 0's
+    # pixels, so the other levels stay clean and the result is exact
+    mgr, plan = _mgr(cfg, StreamConfig(tile_rows=2, delta_threshold=1e-3,
+                                       update_frac=0.25), backend=backend)
     key = jax.random.PRNGKey(31)
     x0 = jax.random.normal(key, (2, N_IN, D))
     mgr.step(x0)
     _single_level_transition(mgr, jax.random.fold_in(key, 1))
     assert mgr._geometry_stale
     assert mgr._transition_levels() == (0,)
-    x1 = x0 + 0.05 * jnp.sign(x0)
+    h0w0 = LEVELS[0][0] * LEVELS[0][1]
+    x1 = x0.at[:, :h0w0].add(0.05 * jnp.sign(x0[:, :h0w0]))
     cache, st = mgr.step(x1)
     assert st["mode"] == "partial" and st["reason"] == "keep-transition"
     assert st["restaged_levels"] == (0,)
@@ -376,6 +380,26 @@ def test_partial_restage_matches_scratch_build(backend):
     assert st["staged_bytes"] == plan.table_bytes_for_rows(
         mgr._slot_offs[1], with_indirection=False) \
         + LEVELS[0][0] * LEVELS[0][1] * 4 + mgr._incr_bytes
+
+
+def test_partial_restage_declines_when_rebuild_is_cheaper():
+    """A one-level transition whose restage plus the incremental update
+    would stage at least a full rebuild's bytes rebuilds instead, so a
+    stream never stages more than rebuilding every frame would."""
+    cfg = _cfg()
+    mgr, plan = _mgr(cfg, StreamConfig(tile_rows=2, delta_threshold=0.0,
+                                       update_frac=1.0))
+    key = jax.random.PRNGKey(31)
+    x0 = jax.random.normal(key, (2, N_IN, D))
+    mgr.step(x0)
+    _single_level_transition(mgr, jax.random.fold_in(key, 1))
+    assert mgr._geometry_stale
+    assert mgr._partial_bytes((0,)) + mgr._incr_bytes >= mgr._full_bytes
+    assert mgr._transition_levels() is None
+    cache, st = mgr.step(x0 + 0.05 * jnp.sign(x0))
+    assert st["mode"] == "rebuild" and st["reason"] == "keep-transition"
+    assert st["staged_bytes"] == mgr._full_bytes
+    assert mgr.report()["partial_frames"] == 0
 
 
 def test_whole_geometry_transition_still_rebuilds():
