@@ -1,0 +1,104 @@
+"""The cell command and the manifest: what the contract of the benchmark
+asks of them, checked without a chip."""
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parents[2]
+MAN = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+CMD = ["--workload", MAN["workloads"][0]["name"], "--seed", "4000000000",
+       "--seconds", "1", "--trace", "0"]
+
+
+def run_command(cwd: Path, env_extra=None):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
+    env.pop("PYTHONPATH", None)
+    return subprocess.run([sys.executable, *MAN["command"][1:], *CMD],
+                          cwd=cwd, env=env, capture_output=True, text=True,
+                          timeout=300)
+
+
+def test_refuses_to_measure_off_a_tpu():
+    r = run_command(ROOT)
+    assert r.returncode == 2, r.stderr
+    assert r.stdout.strip() == ""
+    assert "TPU" in r.stderr
+
+
+def test_refuses_without_the_program(tmp_path):
+    """A directory with only BENCHMARK.json and the benchmark's paths."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    for p in MAN["paths"]:
+        shutil.copytree(ROOT / p, tmp_path / p,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    r = run_command(tmp_path)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+
+
+def test_manifest_keys_and_names():
+    assert set(MAN) == {"command", "paths", "run_seconds", "configs",
+                        "workloads", "end_to_end", "per_layer"}
+    assert MAN["command"][0] == "python3"
+    assert 1 <= MAN["run_seconds"] <= 51
+    cells = len(MAN["workloads"])
+    # a full check of 24 cells fits its allowance
+    assert (2 + 14 * 24) * (MAN["run_seconds"] + 60) + 24 * 180 + 1200 \
+        <= 43200
+    names = [e["name"] for k in ("configs", "workloads", "end_to_end",
+                                 "per_layer") for e in MAN[k]]
+    assert len(names) == len(set(names))
+    assert all(NAME.match(n) for n in names)
+    assert sum(w["chips"] == 4 for w in MAN["workloads"]) <= cells // 2 or \
+        sum(w["chips"] == 4 for w in MAN["workloads"]) <= 1
+    assert len(json.dumps(MAN)) < 64 * 1024
+
+
+def test_every_entry_has_its_files():
+    for c in MAN["configs"]:
+        path = ROOT / c["file"]
+        assert any(c["file"].startswith(p + "/") for p in MAN["paths"])
+        conf = json.loads(path.read_text())
+        assert conf["name"] == c["name"]
+        assert sorted(conf["reduced"]) == sorted(c["reduced"])
+        assert conf["limits"], f"{c['name']} compares nothing"
+    for w in MAN["workloads"]:
+        assert (ROOT / "benchmarks/chip/traffic" / f"{w['traffic']}.json") \
+            .exists()
+        assert w["chips"] in (1, 4)
+        assert w["config"] in {c["name"] for c in MAN["configs"]}
+    for m in MAN["end_to_end"] + MAN["per_layer"]:
+        assert (ROOT / "benchmarks/chip/metrics" / f"{m['name']}.py").exists()
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+
+
+def test_every_cell_reports_what_it_must():
+    e2e = {m["name"]: m for m in MAN["end_to_end"]}
+    assert e2e["setup_s"]["bound"] <= 0.25 and "workloads" not in e2e[
+        "setup_s"]
+    assert all(0.01 <= m["bound"] <= 0.25 for m in e2e.values())
+    assert all(m["source"] in ("host_clock", "device_trace")
+               for m in e2e.values())
+    for w in MAN["workloads"]:
+        own = [m for m in e2e.values() if w["name"] in
+               m.get("workloads", [w["name"]])]
+        assert len(own) >= 2
+        layer = [m for m in MAN["per_layer"] if w["name"] in m["workloads"]]
+        assert layer, w["name"]
+    for m in MAN["per_layer"]:
+        moved = e2e[m["moves"]]
+        for cell in m["workloads"]:
+            assert cell in moved.get("workloads", [cell])
+
+
+@pytest.mark.parametrize("w", MAN["workloads"], ids=lambda w: w["name"])
+def test_why_is_one_short_line(w):
+    assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
