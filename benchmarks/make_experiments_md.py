@@ -340,7 +340,8 @@ def main() -> None:
             f"`{name}` P50 {st['p50_ms']:.2f} ms / P99 {st['p99_ms']:.2f} ms "
             f"(n={st['count']})"
             for name, st in sorted(spans.items())
-            if name in ("queue", "device", "postproc", "callback"))
+            if name in ("queue", "serve.dispatch", "serve.fetch",
+                        "postproc", "callback"))
         obs = serve.get("observability", {})
         parts.append(
             f"\n**Observability (repro/obs/)** — the same run, decomposed by "

@@ -134,10 +134,12 @@ def _obs_overhead(engine_us: float, n: int = 2000) -> dict:
     for i in range(n):
         c.inc(bucket="32", outcome="completed")
         g.set(1.0, bucket="32")
-        for name in ("queue", "device", "postproc"):
-            obs.tracer.end(obs.tracer.start(name, rid=i))
+        obs.tracer.end(obs.tracer.start("queue", rid=i))
+        for name in ("serve.submit", "serve.dispatch", "serve.fetch",
+                     "postproc", "callback"):
+            with obs.tracer.span(name, rid=i, step=i, n=1):
+                pass
         h.observe(1e-3, bucket="32")
-        h.observe(1e-3, span="device")
     per_req_us = (time.perf_counter() - t0) / n * 1e6
     obs.close()
     return {
@@ -221,7 +223,8 @@ def report(dry: bool = False, log=print,
     span_line = ", ".join(
         f"{name} P50 {st['p50_ms']}ms/P99 {st['p99_ms']}ms"
         for name, st in sorted(out["spans"].items())
-        if name in ("queue", "device", "postproc", "callback"))
+        if name in ("queue", "serve.dispatch", "serve.fetch", "postproc",
+                    "callback"))
     log(f"[serve] spans: {span_line}")
     log(f"[serve] obs overhead: "
         f"{out['observability']['instrumentation_us_per_request']} us/req "

@@ -146,25 +146,29 @@ def detector_apply(params: dict, cfg: DetectorConfig, images: jnp.ndarray,
     ``aux["decoder_blocks"]`` carries the per-layer decoder stats and
     the decoder samples ONE shared value cache built from the encoder
     memory under the encoder chain's final FWP compaction."""
-    feats = _pyramid(params, cfg, images)
-    flat = []
-    for f, proj in zip(feats, params["proj"]):
-        b, c, h, w = f.shape
-        flat.append(nn.linear(proj, f.transpose(0, 2, 3, 1).reshape(b, h * w, c)))
-    x_flat = jnp.concatenate(flat, axis=1)                          # (B, N_in, D)
-
+    with jax.named_scope("backbone"):
+        feats = _pyramid(params, cfg, images)
     level_shapes = cfg.level_shapes
-    pos = jnp.concatenate(
-        [nn.sine_pos_embed_2d(h, w, cfg.d_model) for h, w in level_shapes], axis=0)
-    refs = nn.reference_points_for_levels(level_shapes)
+    with jax.named_scope("input_proj"):
+        flat = []
+        for f, proj in zip(feats, params["proj"]):
+            b, c, h, w = f.shape
+            flat.append(nn.linear(
+                proj, f.transpose(0, 2, 3, 1).reshape(b, h * w, c)))
+        x_flat = jnp.concatenate(flat, axis=1)                      # (B, N_in, D)
+        pos = jnp.concatenate(
+            [nn.sine_pos_embed_2d(h, w, cfg.d_model) for h, w in level_shapes],
+            axis=0)
+        refs = nn.reference_points_for_levels(level_shapes)
     enc, aux, state = encoder_apply(
         params["encoder"], cfg.encoder, x_flat, pos, refs, level_shapes,
         collect_stats=collect_stats, backend=encoder_backend(backend),
         return_state=True)
 
     if cfg.decoder is None:
-        cls_logits = nn.linear(params["cls_head"], enc)
-        boxes = jax.nn.sigmoid(nn.linear(params["box_head"], enc))
+        with jax.named_scope("heads"):
+            cls_logits = nn.linear(params["cls_head"], enc)
+            boxes = jax.nn.sigmoid(nn.linear(params["box_head"], enc))
         return cls_logits, boxes, aux
 
     # ---- decoder head: build-once shared cache, N_q learned queries ------
@@ -172,12 +176,13 @@ def detector_apply(params: dict, cfg: DetectorConfig, images: jnp.ndarray,
     hs, dec_refs, dstate = decoder_apply(params["decoder"], cfg.decoder,
                                          plan, enc, state,
                                          collect_stats=collect_stats)
-    cls_logits = nn.linear(params["cls_head"], hs)
-    raw = nn.linear(params["box_head"], hs)
-    # centers refine the decoder's reference points (deformable-DETR)
-    cxy = jax.nn.sigmoid(raw[..., :2] + nn.inverse_sigmoid(dec_refs))
-    wh = jax.nn.sigmoid(raw[..., 2:])
-    boxes = jnp.concatenate([cxy, wh], axis=-1)
+    with jax.named_scope("heads"):
+        cls_logits = nn.linear(params["cls_head"], hs)
+        raw = nn.linear(params["box_head"], hs)
+        # centers refine the decoder's reference points (deformable-DETR)
+        cxy = jax.nn.sigmoid(raw[..., :2] + nn.inverse_sigmoid(dec_refs))
+        wh = jax.nn.sigmoid(raw[..., 2:])
+        boxes = jnp.concatenate([cxy, wh], axis=-1)
     aux = dict(aux)
     aux["decoder_blocks"] = list(dstate.block_stats)
     return cls_logits, boxes, aux

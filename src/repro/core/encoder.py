@@ -59,6 +59,7 @@ def encoder_logical_axes(cfg: EncoderConfig) -> dict:
     return {"blocks": [blk for _ in range(cfg.n_blocks)]}
 
 
+@jax.named_scope("encoder")
 def encoder_apply(
     params: dict,
     cfg: EncoderConfig,
@@ -86,14 +87,19 @@ def encoder_apply(
                      backend=backend)
     h = x_flat
     state = MSDAPipelineState.initial()
-    for blk in params["blocks"]:
-        q = h + pos_embed[None]
-        attn_out, state = msda_attention(
-            blk["attn"], plan, q, ref_points, h,
-            state=state, collect_stats=collect_stats)
-        h = nn.layer_norm(blk["ln1"], h + attn_out)
-        ff = nn.linear(blk["ffn2"], jax.nn.relu(nn.linear(blk["ffn1"], h)))
-        h = nn.layer_norm(blk["ln2"], h + ff)
+    for i, blk in enumerate(params["blocks"]):
+        with jax.named_scope(f"block_{i}"):
+            q = h + pos_embed[None]
+            attn_out, state = msda_attention(
+                blk["attn"], plan, q, ref_points, h,
+                state=state, collect_stats=collect_stats)
+            with jax.named_scope("norm"):
+                h = nn.layer_norm(blk["ln1"], h + attn_out)
+            with jax.named_scope("ffn"):
+                ff = nn.linear(blk["ffn2"],
+                               jax.nn.relu(nn.linear(blk["ffn1"], h)))
+            with jax.named_scope("norm"):
+                h = nn.layer_norm(blk["ln2"], h + ff)
     aux = {"blocks": list(state.block_stats)}
     if return_state:
         return h, aux, state

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import fwp as fwp_lib
@@ -98,6 +99,7 @@ def project_values(params: dict, cfg, x_flat: jnp.ndarray,
     return maybe_fake_quant(v, cfg.act_bits), pix2slot, n_rows
 
 
+@jax.named_scope("msda/value")
 def build_value_cache(params: dict, plan, x_flat: jnp.ndarray,
                       state=None) -> MSDAValueCache:
     """Build the shared value cache for one memory ``x_flat``.

@@ -138,6 +138,7 @@ def _self_attention(layer: dict, h: jnp.ndarray, pos: jnp.ndarray,
     return nn.linear(layer["self_o"], out)
 
 
+@jax.named_scope("decoder")
 def decoder_apply(
     params: dict,
     cfg: MSDADecoderConfig,
@@ -167,7 +168,8 @@ def decoder_apply(
 
     # ---- build ONCE: the shared, optionally FWP-compacted value table ----
     if cache is None:
-        cache = build_value_cache(params["value"], plan, memory, state)
+        with jax.named_scope("cache_build"):
+            cache = build_value_cache(params["value"], plan, memory, state)
     if plan.backend == "pallas_decode":
         # the persistent decode contract: the table was staged at build
         # time, once per memory — a missing staged block would silently
@@ -184,28 +186,34 @@ def decoder_apply(
     refs = jax.nn.sigmoid(nn.linear(params["ref_head"], params["query_pos"]))
     refs = jnp.broadcast_to(refs[None], (b,) + refs.shape)  # (B, Nq, 2)
 
-    for layer in params["layers"]:
-        h = nn.layer_norm(
-            layer["ln_sa"],
-            h + _self_attention(layer, h, pos, attn_cfg.n_heads))
-        # ---- sample everywhere: cross-attention against the SHARED cache.
-        # When the plan carries a query_order, the cached pass derives the
-        # cache-local permutation PER LAYER from this layer's incoming
-        # (pre-refinement) refs — the refinement below shifts every
-        # layer's points, so no permutation survives across layers — and
-        # inverts it on the output, so the ordering is invisible here.
-        attn_out, dstate = msda_attention_cached(
-            layer["cross"], plan, h + pos, refs, dstate.cache,
-            state=dstate, collect_stats=collect_stats, update_fwp=False)
-        h = nn.layer_norm(layer["ln1"], h + attn_out)
-        ff = nn.linear(layer["ffn2"], jax.nn.relu(nn.linear(layer["ffn1"], h)))
-        h = nn.layer_norm(layer["ln2"], h + ff)
-        # ---- per-layer reference-point refinement. The INCOMING refs are
-        # detached (DETR-style truncated chain) but the delta itself is
-        # live: its gradient flows through the later layers' sampling
-        # locations and the final box head, which is what trains the
-        # zero-initialized refinement weights.
-        delta = h @ layer["ref_delta"]["w"] + layer["ref_delta"]["b"]
-        refs = jax.nn.sigmoid(
-            nn.inverse_sigmoid(jax.lax.stop_gradient(refs)) + delta)
+    for j, layer in enumerate(params["layers"]):
+        with jax.named_scope(f"layer_{j}"):
+            with jax.named_scope("self_attn"):
+                sa = _self_attention(layer, h, pos, attn_cfg.n_heads)
+            with jax.named_scope("norm"):
+                h = nn.layer_norm(layer["ln_sa"], h + sa)
+            # ---- sample everywhere: cross-attention against the SHARED cache.
+            # When the plan carries a query_order, the cached pass derives the
+            # cache-local permutation PER LAYER from this layer's incoming
+            # (pre-refinement) refs — the refinement below shifts every
+            # layer's points, so no permutation survives across layers — and
+            # inverts it on the output, so the ordering is invisible here.
+            attn_out, dstate = msda_attention_cached(
+                layer["cross"], plan, h + pos, refs, dstate.cache,
+                state=dstate, collect_stats=collect_stats, update_fwp=False)
+            with jax.named_scope("norm"):
+                h = nn.layer_norm(layer["ln1"], h + attn_out)
+            with jax.named_scope("ffn"):
+                ff = nn.linear(layer["ffn2"],
+                               jax.nn.relu(nn.linear(layer["ffn1"], h)))
+            with jax.named_scope("norm"):
+                h = nn.layer_norm(layer["ln2"], h + ff)
+            # ---- per-layer reference-point refinement. The INCOMING refs are
+            # detached (DETR-style truncated chain) but the delta itself is
+            # live: its gradient flows through the later layers' sampling
+            # locations and the final box head, which is what trains the
+            # zero-initialized refinement weights.
+            delta = h @ layer["ref_delta"]["w"] + layer["ref_delta"]["b"]
+            refs = jax.nn.sigmoid(
+                nn.inverse_sigmoid(jax.lax.stop_gradient(refs)) + delta)
     return h, refs, dstate

@@ -30,11 +30,9 @@ class Observability:
 
     @classmethod
     def create(cls, jsonl_path: Optional[str] = None,
-               capacity: int = 4096,
-               xla_annotations: bool = False) -> "Observability":
+               capacity: int = 4096) -> "Observability":
         return cls(MetricsRegistry(),
-                   Tracer(capacity=capacity, jsonl_path=jsonl_path,
-                          xla_annotations=xla_annotations))
+                   Tracer(capacity=capacity, jsonl_path=jsonl_path))
 
     @classmethod
     def default(cls, capacity: int = 4096) -> "Observability":

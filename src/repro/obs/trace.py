@@ -5,8 +5,10 @@ serve engine opens spans across threads (``queue`` starts on the
 submit thread, ``postproc`` ends on the worker thread), so ``start``
 returns an opaque span id and ``end`` may be called from anywhere.
 Single-thread scopes use the ``span(...)`` context manager, which also
-carries the opt-in ``jax.profiler.TraceAnnotation`` bridge so spans
-line up with XLA traces on real hardware.
+writes the scope into the profiler's trace as a
+``jax.profiler.TraceAnnotation`` carrying ``rid`` and the attributes, so
+host spans lie on the device trace's clock (about a microsecond a span
+when no trace is recording).
 
 All timestamps are ``time.perf_counter()`` — monotonic by contract.
 ``end`` asserts it: a negative-duration span raises ``ValueError``
@@ -35,6 +37,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 _tracer_ids = itertools.count(1)
 
@@ -71,8 +75,7 @@ class Tracer:
     enabled = True
 
     def __init__(self, capacity: int = 4096,
-                 jsonl_path: Optional[str] = None,
-                 xla_annotations: bool = False) -> None:
+                 jsonl_path: Optional[str] = None) -> None:
         self._prefix = f"t{next(_tracer_ids)}"
         self._seq = itertools.count(1)
         self._open: Dict[str, Span] = {}
@@ -80,7 +83,6 @@ class Tracer:
         self.spans: Deque[Span] = collections.deque(maxlen=capacity)
         self.jsonl_path = jsonl_path
         self._sink = None
-        self.xla_annotations = xla_annotations
         if jsonl_path:
             # line-buffered append: whole-line writes interleave safely
             # when several tracers in one process share a path
@@ -134,25 +136,16 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, rid: Optional[object] = None, **attrs):
-        """Same-thread scope.  With ``xla_annotations=True`` the scope is
-        also pushed as a ``jax.profiler.TraceAnnotation`` so host spans
-        line up with XLA device traces (best-effort: silently skipped
-        when the profiler is unavailable)."""
-        ann = None
-        if self.xla_annotations:
+        """Same-thread scope, also written to the profiler's trace (a
+        ``TraceAnnotation`` named ``name`` with ``rid`` and ``attrs`` as
+        its stats) when one is recording."""
+        stats = attrs if rid is None else dict(attrs, rid=rid)
+        with TraceAnnotation(name, **stats):
+            span_id = self.start(name, rid, **attrs)
             try:
-                from jax.profiler import TraceAnnotation
-                ann = TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
-        span_id = self.start(name, rid, **attrs)
-        try:
-            yield span_id
-        finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            self.end(span_id)
+                yield span_id
+            finally:
+                self.end(span_id)
 
     # -- aggregation ----------------------------------------------------
     def open_count(self) -> int:

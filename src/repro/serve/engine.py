@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import nn
-from repro.obs import Observability
+from repro.obs import Observability, hlo_scopes
 from repro.serve.buckets import BucketRouter, ShapeBucket, derive_buckets
 from repro.serve.postproc import (PostprocWorker, StarvationError,
                                   softmax_np, topk_detections)
@@ -59,7 +59,10 @@ class DetrRequest:
     error: Optional[str] = None               # admission rejection reason
     callback: Optional[Callable] = None       # invoked on completion
     t_submit: float = 0.0
+    t_dispatched: float = 0.0                 # its batch's dispatch returned
     t_done: float = 0.0
+    step: Optional[int] = None                # engine step that dispatched
+    #   it: the ``step`` of its ``serve.dispatch``/``serve.fetch`` spans
     span_queue: Optional[str] = None          # open "queue" span id — the
     #   request context that carries the trace across the worker thread
 
@@ -112,8 +115,6 @@ class DetrServeEngine:
         self._m_latency = m.histogram(
             "serve_request_latency_seconds",
             "submit-to-callback latency per completed request")
-        self._m_span = m.histogram(
-            "serve_span_seconds", "per-stage latency (label span=)")
         self._m_staged = m.counter(
             "staged_bytes_total",
             "bytes staged to device per the plan's static accounting")
@@ -127,6 +128,10 @@ class DetrServeEngine:
         self.finished: list[DetrRequest] = []
         self.rejected: list[DetrRequest] = []
         self._lock = threading.Lock()
+        self._steps = 0            # batches dispatched (this thread)
+        self._fetched = 0          # batches whose results reached the host
+        #   (post-processing stage only): the difference is what is in
+        #   flight on the device
         self._compiled = {}
         self.compile_seconds: dict[int, float] = {}
         for b in self.buckets:
@@ -173,6 +178,13 @@ class DetrServeEngine:
     def bucket_table(self) -> list:
         return self.router.table()
 
+    def op_scopes(self, resolution: int) -> dict:
+        """``{HLO instruction name: scope path}`` of the bucket's compiled
+        forward (:func:`repro.obs.hlo_scopes`): the layer each device
+        operation of a profiler trace belongs to, e.g.
+        ``encoder/block_3/msda/sample``."""
+        return hlo_scopes(self._compiled[resolution])
+
     def pending(self) -> int:
         """Requests admitted but not yet dispatched to the device."""
         return sum(len(q) for q in self.queues.values())
@@ -181,24 +193,25 @@ class DetrServeEngine:
     def submit(self, req: DetrRequest) -> bool:
         """Route a request to its bucket queue; returns False (and records
         the reason on ``req.error``) when admission control rejects it."""
-        req.t_submit = time.perf_counter()
-        bucket, reason = self.router.admit(req.image)
-        if bucket is None:
-            req.error = reason
-            self._m_requests.inc(bucket="none", outcome="rejected")
-            with self._lock:
-                self.rejected.append(req)
-            return False
-        res = bucket.resolution
-        req.bucket = res
-        # the "queue" span opens here and is closed by step() at dispatch;
-        # its id rides on the request (the cross-thread trace context)
-        req.span_queue = self.obs.tracer.start("queue", rid=req.rid,
-                                               t=req.t_submit, bucket=res)
-        self._m_requests.inc(bucket=str(res), outcome="admitted")
-        self.queues[res].append(req)
-        self._m_qdepth.set(len(self.queues[res]), bucket=str(res))
-        return True
+        with self.obs.tracer.span("serve.submit", rid=req.rid):
+            req.t_submit = time.perf_counter()
+            bucket, reason = self.router.admit(req.image)
+            if bucket is None:
+                req.error = reason
+                self._m_requests.inc(bucket="none", outcome="rejected")
+                with self._lock:
+                    self.rejected.append(req)
+                return False
+            res = bucket.resolution
+            req.bucket = res
+            # the "queue" span opens here and is closed by step() at dispatch;
+            # its id rides on the request (the cross-thread trace context)
+            req.span_queue = self.obs.tracer.start("queue", rid=req.rid,
+                                                   t=req.t_submit, bucket=res)
+            self._m_requests.inc(bucket=str(res), outcome="admitted")
+            self.queues[res].append(req)
+            self._m_qdepth.set(len(self.queues[res]), bucket=str(res))
+            return True
 
     # ---- one engine step ---------------------------------------------------
     def step(self) -> int:
@@ -216,56 +229,57 @@ class DetrServeEngine:
         self._m_qdepth.set(len(q), bucket=str(res))
         for req in batch:
             if req.span_queue:
-                sp = tr.end(req.span_queue)
+                tr.end(req.span_queue)
                 req.span_queue = None
-                self._m_span.observe(sp.duration_s, span="queue")
-        imgs = np.zeros((self.max_batch, 3, res, res), np.float32)
-        for i, req in enumerate(batch):
-            im = np.asarray(req.image, np.float32)
-            imgs[i, :, :im.shape[1], :im.shape[2]] = im     # pad up
-        # the "device" span opens at dispatch and is closed by the
-        # postproc stage once the transfer completes (worker thread)
-        dev_span = tr.start("device", bucket=res, n=len(batch))
-        cls_logits, boxes, _aux = self._compiled[res](self.params,
-                                                      jnp.asarray(imgs))
+        step = self._steps
+        self._steps += 1
+        with tr.span("serve.dispatch", step=step, n=len(batch), bucket=res,
+                     inflight=step - self._fetched):
+            imgs = np.zeros((self.max_batch, 3, res, res), np.float32)
+            for i, req in enumerate(batch):
+                im = np.asarray(req.image, np.float32)
+                imgs[i, :, :im.shape[1], :im.shape[2]] = im     # pad up
+            cls_logits, boxes, _aux = self._compiled[res](self.params,
+                                                          jnp.asarray(imgs))
+        t = time.perf_counter()
+        for req in batch:
+            req.step, req.t_dispatched = step, t
         # build-once value cache per dispatched memory (static accounting)
         self._m_staged.inc(
             self._bucket_by_res[res].plan.cache_table_bytes, mode="build")
         # hand the device arrays straight to the postproc stage: the
         # worker's np.asarray blocks on the transfer while this thread is
         # free to dispatch the next bucket's micro-batch
-        self._post.submit((batch, cls_logits, boxes, dev_span))
+        self._post.submit((step, batch, cls_logits, boxes))
         self._m_backlog.set(self._post.backlog)
         return len(batch)
 
     def _complete(self, item) -> None:
-        batch, cls_logits, boxes, dev_span = item
+        step, batch, cls_logits, boxes = item
         tr = self.obs.tracer
-        cls_logits = np.asarray(cls_logits)
-        probs = softmax_np(cls_logits)
-        boxes = np.asarray(boxes)
-        if dev_span:
-            sp = tr.end(dev_span)    # after np.asarray: transfer included
-            self._m_span.observe(sp.duration_s, span="device")
-        post_span = tr.start("postproc", n=len(batch))
-        for i, req in enumerate(batch):
-            req.cls_logits = cls_logits[i]
-            req.cls_probs = probs[i]
-            req.boxes = boxes[i]
-            req.detections = topk_detections(probs[i], boxes[i], self.topk)
-            req.t_done = time.perf_counter()
-            req.done = True
-            if req.callback is not None:
-                with tr.span("callback", rid=req.rid):
-                    req.callback(req)
-            self._m_latency.observe(req.t_done - req.t_submit,
-                                    bucket=str(req.bucket))
-            self._m_requests.inc(bucket=str(req.bucket), outcome="completed")
-            with self._lock:
-                self.finished.append(req)
-        if post_span:
-            sp = tr.end(post_span)
-            self._m_span.observe(sp.duration_s, span="postproc")
+        with tr.span("serve.fetch", step=step, n=len(batch)):
+            cls_logits = np.asarray(cls_logits)
+            probs = softmax_np(cls_logits)
+            boxes = np.asarray(boxes)
+        self._fetched += 1
+        with tr.span("postproc", step=step, n=len(batch)):
+            for i, req in enumerate(batch):
+                req.cls_logits = cls_logits[i]
+                req.cls_probs = probs[i]
+                req.boxes = boxes[i]
+                req.detections = topk_detections(probs[i], boxes[i],
+                                                 self.topk)
+                req.t_done = time.perf_counter()
+                req.done = True
+                if req.callback is not None:
+                    with tr.span("callback", rid=req.rid):
+                        req.callback(req)
+                self._m_latency.observe(req.t_done - req.t_submit,
+                                        bucket=str(req.bucket))
+                self._m_requests.inc(bucket=str(req.bucket),
+                                     outcome="completed")
+                with self._lock:
+                    self.finished.append(req)
 
     def drain(self) -> None:
         """Barrier on the post-processing stage only (no new dispatches)."""

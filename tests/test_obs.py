@@ -3,10 +3,13 @@ gauges / fixed-bucket histograms + Prometheus round-trip), the span
 tracer (ring buffer, JSONL sink, cross-thread end, negative-duration
 guard), the engine instrumentation contracts (compile counter == bucket
 count, latency histogram == completed requests, <1% overhead), the
-streaming counters vs ``mgr.report()``, ``MSDAPlan.snapshot()``
-consistency, the JSONL/Prometheus validator, and the dashboard
-renderer on synthetic events."""
+named scopes of the compiled forward and the engine's spans in a
+profiler trace, the streaming counters vs ``mgr.report()``,
+``MSDAPlan.snapshot()`` consistency, the JSONL/Prometheus validator, and
+the dashboard renderer on synthetic events."""
+import glob
 import json
+import re
 import threading
 import time
 
@@ -15,7 +18,7 @@ import numpy as np
 import pytest
 
 from repro.obs import (MetricsRegistry, NullRegistry, NullTracer,
-                       Observability, Tracer, json_snapshot,
+                       Observability, Tracer, hlo_scopes, json_snapshot,
                        parse_prometheus_text, prometheus_text)
 from repro.obs.metrics import DEFAULT_BYTES_BUCKETS, default_registry
 
@@ -261,10 +264,10 @@ def test_engine_metrics_compile_counter_and_latency_histogram():
     req = m.get("serve_requests_total")
     assert req.value(bucket="32", outcome="admitted") == 3
     assert req.value(outcome="completed", bucket="32") == 3
-    # every request produced a queue + device + postproc span
+    # every request produced a queue + serve.fetch + postproc span
     stats = engine.obs.tracer.span_stats()
     assert stats["queue"]["count"] == rid
-    assert stats["device"]["count"] >= 1
+    assert stats["serve.fetch"]["count"] >= 1
     assert stats["postproc"]["count"] >= 1
 
     # (iii) overhead: deterministic per-request instrumentation cost
@@ -277,10 +280,12 @@ def test_engine_metrics_compile_counter_and_latency_histogram():
     t0 = time.perf_counter()
     for i in range(n):
         c.inc(bucket="32", outcome="completed")
-        for name in ("queue", "device", "postproc"):
-            probe.tracer.end(probe.tracer.start(name, rid=i))
+        probe.tracer.end(probe.tracer.start("queue", rid=i))
+        for name in ("serve.submit", "serve.dispatch", "serve.fetch",
+                     "postproc", "callback"):
+            with probe.tracer.span(name, rid=i, step=i, n=1):
+                pass
         h.observe(1e-3, bucket="32")
-        h.observe(1e-3, span="device")
     per_req_s = (time.perf_counter() - t0) / n
     probe.close()
     assert per_req_s < 0.01 * mean_req_s, \
@@ -314,6 +319,95 @@ def test_disabled_engine_serves_identically_with_empty_registry():
     assert engine.obs.metrics.snapshot()["counters"] == {}
     assert engine.compile_count == 0        # null counter: the view reads 0
     engine.close()
+
+
+# the layers the forward names (jax.named_scope), and what lies under them
+FIXED_TREE = re.compile(r"(backbone|input_proj|heads|encoder/block_\d+"
+                        r"|decoder(/cache_build|/layer_\d+)?)(/.+)?")
+INSTR = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = .*?\s([a-z][\w-]*)\(")
+
+
+def test_hlo_scopes_name_the_layers_of_the_compiled_forward():
+    """Every dot of the compiled forward, and all but a few of its entry
+    instructions, fall under the fixed tree of scopes; each MSDA call
+    shows its five stages."""
+    engine = _tiny_engine()
+    text = engine._compiled[32].as_text()
+    scopes = engine.op_scopes(32)
+    assert scopes == hlo_scopes(text)
+    ops = [m.groups() for m in map(INSTR.match, text.splitlines()) if m]
+    dots = [n for n, op in ops if op == "dot"]
+    assert dots and all(FIXED_TREE.fullmatch(scopes[n]) for n in dots)
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    counted = [n for n, op in (m.groups() for m in
+                               map(INSTR.match, entry.splitlines()) if m)
+               if op not in ("bitcast", "parameter", "tuple")]
+    mapped = [n for n in counted if FIXED_TREE.fullmatch(scopes[n])]
+    assert len(mapped) >= 0.95 * len(counted)
+    layers = {"/".join(p.split("/")[:2]) for p in scopes.values()}
+    assert {"backbone", "input_proj", "encoder/block_0",
+            "decoder/cache_build", "decoder/layer_0", "decoder/layer_1",
+            "heads"} <= layers
+    stages = {p.split("/msda/")[1].split("/")[0]
+              for p in scopes.values() if "/msda/" in p}
+    assert {"value", "points", "sample", "out", "fwp"} <= stages
+    engine.close()
+
+
+def _trace_events(path):
+    """{name: [stats]} of the host events in a recorded profiler trace
+    (stats read for the engine's spans only)."""
+    from jax.profiler import ProfileData
+    prof = ProfileData.from_file(
+        glob.glob(str(path / "**" / "*.xplane.pb"), recursive=True)[0])
+    out = {}
+    for plane in prof.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        dict(ev.stats) if ev.name.startswith("serve.")
+                        else None)
+    return out
+
+
+def _serve_traced(engine, path, n=3):
+    from tests.test_serve import _images
+    from repro.serve.engine import DetrRequest
+    jax.profiler.start_trace(str(path))
+    try:
+        for i, im in enumerate(_images(n, 32)):
+            assert engine.submit(DetrRequest(rid=i, image=im))
+        engine.run_until_drained()
+    finally:
+        jax.profiler.stop_trace()
+    engine.close()
+    return _trace_events(path)
+
+
+def test_engine_spans_reach_the_profiler_trace(tmp_path):
+    """serve.submit, serve.dispatch (with its step, batch size, bucket and
+    batches in flight) and serve.fetch are host events of the trace."""
+    events = _serve_traced(_tiny_engine(), tmp_path)
+    assert len(events["serve.submit"]) == 3
+    assert {"serve.fetch", "postproc"} <= events.keys()
+    dispatch = events["serve.dispatch"]           # 3 requests, max_batch 2
+    assert [d["step"] for d in dispatch] == [0, 1]
+    assert [d["n"] for d in dispatch] == [2, 1]
+    assert all(d["bucket"] == 32 and d["inflight"] >= 0 for d in dispatch)
+    assert sorted(d["step"] for d in events["serve.fetch"]) == [0, 1]
+
+
+def test_disabled_engine_puts_nothing_in_the_trace(tmp_path):
+    from repro.serve.engine import DetrServeEngine
+    from tests.test_serve import _params, _tiny_cfg
+    cfg = _tiny_cfg()
+    engine = DetrServeEngine(cfg, _params(cfg), max_batch=2,
+                             resolutions=(32,), obs=Observability.disabled())
+    events = _serve_traced(engine, tmp_path)
+    assert not [n for n in events if n.startswith("serve.")
+                or n in ("postproc", "callback")]
 
 
 def test_streaming_manager_counters_match_report():

@@ -7,13 +7,15 @@ AOT-compile each Pallas MSDA kernel at the paper's widths (800x1333
 four-level pyramid, d_model 256, 8 heads, 4 points; the decoder at 300
 queries x 6 layers) and the serving forward at full widths on a 256-px
 bucket, and check that the kernels compiled natively
-(``tpu_custom_call``). Nothing runs, so nothing here says a result is
+(``tpu_custom_call``) and carry their layer's scope. Nothing runs, so nothing here says a result is
 right; the kernels' numbers are checked in interpret mode elsewhere.
 
 The topology is described inside a fixture (never at import), so every
 pytest-xdist worker collects the same tests; the file skips where no TPU
 compiler is installed.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.detr_family import CONFIGS, LEVEL_SHAPES
 from repro.core import fwp as fwp_lib
+from repro.obs import hlo_scopes
 
 N_IN = sum(h * w for h, w in LEVEL_SHAPES)
 B, H, DH, K = 1, 8, 32, 16
@@ -149,3 +152,9 @@ def test_serve_forward_compiles_for_v5e(one_chip, monkeypatch):
                    params, img)
     mem = exe.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    # every MSDA kernel sits in the sampling stage of its layer's scope
+    scopes = hlo_scopes(exe)
+    kernels = [n for n in scopes if n.startswith("msgs_")]
+    assert len(kernels) == 12
+    assert all(re.match(r"(encoder/block_\d|decoder/layer_\d)/msda/sample",
+                        scopes[k]) for k in kernels)
