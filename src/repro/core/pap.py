@@ -56,6 +56,15 @@ def pap_topk_select(probs: jnp.ndarray, k: int,
                         keep_frac=kept_frac)
 
 
+def keeps_point_order(mode: str) -> bool:
+    """Whether ``pap_select`` in ``mode`` keeps the whole L*P point axis in
+    its own order (``point_idx`` is ``arange(L*P)`` broadcast).
+
+    A static fact of the mode: a caller may then index points by their
+    position and skip the gather by ``point_idx``."""
+    return mode in ("off", "threshold")
+
+
 def pap_select(probs: jnp.ndarray, mode: str, *, threshold: float, k: int) -> PAPSelection:
     if mode == "off":
         lp = probs.shape[-1]

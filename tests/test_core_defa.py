@@ -1,6 +1,7 @@
 """DEFA algorithm tests: exactness contracts, pruning invariants, quant
 bounds, and hypothesis property tests on the paper's mechanisms."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +16,7 @@ from repro.core import pap as pap_lib
 from repro.core.msdeform_attn import (
     MSDeformAttnConfig, init_msdeform_attn, msdeform_attn_apply,
     msdeform_attn_ref)
-from repro.core.quant import fake_quant, quant_scale
+from repro.core.quant import fake_quant, maybe_fake_quant, quant_scale
 
 LEVELS = ((16, 20), (8, 10), (4, 5), (2, 3))
 N_IN = sum(h * w for h, w in LEVELS)
@@ -275,3 +276,125 @@ def test_fwp_state_slots_bijective(k):
     # surviving pixels (mask) are exactly those with a slot
     mask = np.asarray(state.keep_mask[0])
     assert ((p2s < cap) == mask).all()
+
+
+# --------------------------------------------------------------------------
+# point generation: the identity selection needs no gather
+# --------------------------------------------------------------------------
+
+def _points_by_gather(params, cfg, query, ref_points, level_shapes):
+    """Point generation as a gather by ``point_idx``, whatever the PAP mode:
+    the formulation every mode used before the identity path existed.
+    Returns (sel, offs_k, SamplingPoints fields as a dict)."""
+    b, nq, _ = query.shape
+    h, p, lp = cfg.n_heads, cfg.n_points, cfg.n_lp
+    wq = lambda w: maybe_fake_quant(w, cfg.weight_bits)
+    logits = jnp.einsum("bnd,dhk->bnhk", query, wq(params["attn_w"])) \
+        + params["attn_b"]
+    probs = maybe_fake_quant(jax.nn.softmax(logits, axis=-1), cfg.act_bits)
+    sel = pap_lib.pap_select(probs, cfg.pap_mode,
+                             threshold=cfg.pap_threshold, k=cfg.pap_keep)
+    offs = jnp.einsum("bnd,dhk->bnhk", query, wq(params["offs_w"])) \
+        + params["offs_b"]
+    offs = offs.reshape(b, nq, h, lp, 2)
+    offs_k = jnp.take_along_axis(
+        offs, sel.point_idx[..., None].astype(jnp.int32), axis=3)
+    lvl_of_pt = (sel.point_idx // p).astype(jnp.int32)
+    if cfg.range_narrow is not None:
+        bounds = jnp.take(jnp.asarray(cfg.range_narrow, query.dtype),
+                          lvl_of_pt)
+        offs_k = jnp.clip(offs_k, -bounds[..., None], bounds[..., None])
+    offs_k = maybe_fake_quant(offs_k, cfg.act_bits)
+    starts, _ = fwp_lib.level_starts(level_shapes)
+    ws = jnp.asarray([w for _, w in level_shapes], jnp.int32)
+    hs = jnp.asarray([hh for hh, _ in level_shapes], jnp.int32)
+    wl = jnp.take(ws, lvl_of_pt)
+    hl = jnp.take(hs, lvl_of_pt)
+    st = jnp.take(jnp.asarray(starts), lvl_of_pt)
+    refs = ref_points.astype(jnp.float32)
+    o = offs_k.astype(jnp.float32)
+    pts = dict(
+        x_px=refs[:, :, None, None, 0] * wl.astype(jnp.float32) + o[..., 0] - 0.5,
+        y_px=refs[:, :, None, None, 1] * hl.astype(jnp.float32) + o[..., 1] - 0.5,
+        start=st, wl=wl, hl=hl, lvl_of_pt=lvl_of_pt)
+    return sel, offs_k, pts
+
+
+def _point_case(pap_mode, dtype, narrow):
+    """A config, params with offsets that vary by query, a query batch."""
+    cfg = MSDeformAttnConfig(
+        d_model=D, n_heads=4, pap_mode=pap_mode, pap_threshold=0.05,
+        pap_keep=5, dtype=dtype,
+        range_narrow=(6.0, 4.0, 3.0, 2.0) if narrow else None,
+        act_bits=12 if narrow else None)
+    key = jax.random.PRNGKey(7)
+    params = init_msdeform_attn(key, cfg)
+    k1, k2, k3 = jax.random.split(key, 3)
+    params["offs_w"] = (jax.random.normal(k1, params["offs_w"].shape)
+                        * 0.5).astype(dtype)
+    q = jax.random.normal(k2, (B, NQ, D)).astype(dtype)
+    refp = jax.random.uniform(k3, (B, NQ, 2))
+    return cfg, params, q, refp
+
+
+POINT_MODES = ("off", "threshold", "topk")
+
+
+@pytest.mark.parametrize("narrow", (False, True), ids=("plain", "narrow_int12"))
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16),
+                         ids=("f32", "bf16"))
+@pytest.mark.parametrize("pap_mode", POINT_MODES)
+def test_points_equal_the_gather_formulation(pap_mode, dtype, narrow):
+    """select_points and generate_points give, bit for bit, what a gather
+    by point_idx gives: the identity path ("off", "threshold") reads the
+    offsets and level geometry from the point axis's structure, "topk"
+    keeps its gather. Op by op, as here: under jit the compiler may fuse
+    the ungathered offsets into the coordinate arithmetic and round them
+    otherwise."""
+    from repro.msda.sampling import generate_points, select_points
+    cfg, params, q, refp = _point_case(pap_mode, dtype, narrow)
+    want_sel, want_offs, want_pts = _points_by_gather(params, cfg, q, refp,
+                                                      LEVELS)
+    sel, offs_k, lvl_of_pt = select_points(params, cfg, q)
+    sel2, pts = generate_points(params, cfg, q, refp, LEVELS)
+    for got in (sel, sel2):
+        for name, want in want_sel._asdict().items():
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          np.asarray(want), err_msg=name)
+    assert offs_k.dtype == want_offs.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(offs_k), np.asarray(want_offs))
+    np.testing.assert_array_equal(np.asarray(lvl_of_pt),
+                                  np.asarray(want_pts["lvl_of_pt"]))
+    for name, want in want_pts.items():
+        got = getattr(pts, name)
+        assert got.shape == want.shape and got.dtype == want.dtype, name
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("narrow", (False, True), ids=("plain", "narrow_int12"))
+@pytest.mark.parametrize("pap_mode", POINT_MODES)
+def test_point_generation_lowers_to_a_gather_only_for_topk(pap_mode, narrow):
+    """The StableHLO of a jitted generate_points holds no gather when PAP
+    keeps every point in order, and holds one for "topk"'s data-dependent
+    selection: dense serving cannot silently regain the identity gather."""
+    from repro.msda.sampling import generate_points
+    cfg, params, q, refp = _point_case(pap_mode, jnp.bfloat16, narrow)
+    text = jax.jit(
+        lambda p, q, r: generate_points(p, cfg, q, r, LEVELS)
+    ).lower(params, q, refp).as_text()
+    n_gather = len(re.findall(r"stablehlo\.gather[^<]", text))
+    if pap_mode == "topk":
+        assert n_gather >= 1
+    else:
+        assert n_gather == 0, n_gather
+
+
+@pytest.mark.parametrize("pap_mode", POINT_MODES)
+def test_point_generation_rejects_a_pyramid_of_other_depth(pap_mode):
+    """A config of 4 levels over a 3-level pyramid has points on a level
+    that does not exist: refused, where a gather would read out of bounds."""
+    from repro.msda.sampling import generate_points
+    cfg, params, q, refp = _point_case(pap_mode, jnp.float32, False)
+    with pytest.raises(ValueError, match="3 per-level values"):
+        generate_points(params, cfg, q, refp, LEVELS[:3])

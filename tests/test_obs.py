@@ -437,6 +437,49 @@ def test_streaming_manager_counters_match_report():
     assert all(st["total_s"] >= 0 for st in stats.values())
 
 
+def _six_by_six_cfg(pap_mode):
+    """_tiny_cfg at 6 encoder blocks and 6 decoder layers, dense as the
+    served configuration is, under ``pap_mode``."""
+    import dataclasses
+    from tests.test_serve import _tiny_cfg
+    cfg = _tiny_cfg()
+    attn = dataclasses.replace(cfg.encoder.attn, pap_mode=pap_mode,
+                               pap_keep=4, fwp_mode="off", range_narrow=None)
+    return dataclasses.replace(
+        cfg, encoder=dataclasses.replace(cfg.encoder, attn=attn, n_blocks=6),
+        decoder=dataclasses.replace(cfg.decoder, n_layers=6))
+
+
+def _point_select_traces():
+    c = default_registry().counter("msda_point_select_traces_total")
+    return {p: c.value(path=p) for p in ("identity", "gather")}
+
+
+def test_point_select_counter_reads_the_path_of_every_msda_call():
+    """Compiling the dense served forward takes the identity path in each
+    of its 12 MSDA calls (6 encoder blocks + 6 decoder layers) and the
+    gather path in none; tracing a "topk" forward takes only the gather."""
+    from tests.test_serve import _params
+    from repro.core.detector import detector_apply
+    from repro.serve.engine import DetrServeEngine
+    cfg = _six_by_six_cfg("off")
+    before = _point_select_traces()
+    engine = DetrServeEngine(cfg, _params(cfg), max_batch=1,
+                             resolutions=(32,), obs=Observability.disabled())
+    engine.close()
+    after = _point_select_traces()
+    assert after["identity"] - before["identity"] == 12
+    assert after["gather"] == before["gather"]
+
+    cfg = _six_by_six_cfg("topk")
+    params = _params(cfg)
+    images = jax.ShapeDtypeStruct((1, 3, 32, 32), np.float32)
+    jax.eval_shape(lambda p, x: detector_apply(p, cfg, x)[:2], params, images)
+    final = _point_select_traces()
+    assert final["gather"] - after["gather"] == 12
+    assert final["identity"] == after["identity"]
+
+
 # --------------------------------------------------------------------------
 # plan snapshot
 # --------------------------------------------------------------------------

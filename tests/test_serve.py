@@ -219,8 +219,8 @@ def test_streaming_session_churn_accounting():
     from repro.serve.engine import StreamingDetrEngine
     from repro.stream import StreamConfig, drifting_scene
     levels = ((8, 10), (4, 5), (2, 3))
-    attn = MSDeformAttnConfig(d_model=32, n_heads=4, fwp_mode="compact",
-                              fwp_k=1.0, fwp_capacity=0.6,
+    attn = MSDeformAttnConfig(d_model=32, n_heads=4, n_levels=len(levels),
+                              fwp_mode="compact", fwp_k=1.0, fwp_capacity=0.6,
                               range_narrow=(4.0, 3.0, 2.0))
     dec = msda.MSDADecoderConfig(n_layers=2, n_queries=8, d_ffn=32)
     key = jax.random.PRNGKey(3)
@@ -384,8 +384,8 @@ def test_streaming_capacity_estimate_reports_budget_source():
     from repro.msda import plan as plan_lib
     from repro.serve.engine import StreamingDetrEngine
     levels = ((8, 10), (4, 5), (2, 3))
-    attn = MSDeformAttnConfig(d_model=32, n_heads=4, fwp_mode="compact",
-                              fwp_k=1.0, fwp_capacity=0.6,
+    attn = MSDeformAttnConfig(d_model=32, n_heads=4, n_levels=len(levels),
+                              fwp_mode="compact", fwp_k=1.0, fwp_capacity=0.6,
                               range_narrow=(4.0, 3.0, 2.0))
     dec = msda.MSDADecoderConfig(n_layers=2, n_queries=8, d_ffn=32)
     key = jax.random.PRNGKey(3)

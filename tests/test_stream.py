@@ -23,8 +23,8 @@ D = 32
 
 
 def _cfg(**kw):
-    base = dict(d_model=D, n_heads=4, fwp_mode="compact", fwp_k=1.0,
-                fwp_capacity=0.6, range_narrow=(4.0, 3.0, 2.0))
+    base = dict(d_model=D, n_heads=4, n_levels=len(LEVELS), fwp_mode="compact",
+                fwp_k=1.0, fwp_capacity=0.6, range_narrow=(4.0, 3.0, 2.0))
     base.update(kw)
     return MSDeformAttnConfig(**base)
 
