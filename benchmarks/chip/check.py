@@ -2,9 +2,10 @@
 
 Once the window has closed and the memory peak is read, a sample of the
 requests the window finished (drawn from the seed, the largest image
-always in it) is run through the plain float32 reference
-(``reference.py``) on the request's own padded image, with the same
-weights made again from the seed. For each compared number the worst
+always in it) is run through the plain float32 reference of the
+configuration's architecture (``arch/<name>.py``, built on
+``reference.py``) on the request's own image on its canvas, with the
+same weights made again from the seed. For each compared number the worst
 request of the sample counts:
 
 * ``logits_err``: max |served - reference| over the request's class
@@ -33,7 +34,7 @@ import time
 
 import numpy as np
 
-from benchmarks.chip import reference, traffic as traffic_lib, weights
+from benchmarks.chip import traffic as traffic_lib
 from benchmarks.chip.model import Model
 
 NUMBERS = ("logits_err", "boxes_err", "logits_med", "boxes_med")
@@ -48,20 +49,15 @@ def rel_err(got, want) -> float:
                  / max(1.0, float(np.max(np.abs(want)))))
 
 
-def padded(image: np.ndarray, size: int) -> np.ndarray:
-    out = np.zeros((3, size, size), np.float32)
-    out[:, :image.shape[1], :image.shape[2]] = image
-    return out
-
-
 def reference_outputs(m: Model, seed: int, images: list,
                       control: bool = False) -> list:
     """[(logits, boxes)] of the reference for each image."""
-    params = weights.to_f32(weights.make_params(seed, m))
-    fn = reference.compiled(m, control)
+    arch = m.arch
+    params = arch.to_f32(arch.make_params(seed, m))
+    fn = arch.reference(m, control)
     out = []
     for img in images:
-        logits, boxes = fn(params, padded(img, m.input_size))
+        logits, boxes = fn(params, arch.canvas(img, m))
         out.append((np.asarray(logits), np.asarray(boxes)))
     return out
 
@@ -89,11 +85,11 @@ def pick(outputs: list, k: int, seed: int) -> list:
 
 
 def malformed(m: Model, outputs: list) -> int:
+    want_logits, want_boxes = m.arch.output_shapes(m)
     bad = 0
     for _, logits, boxes in outputs:
         ok = (logits is not None and boxes is not None
-              and logits.shape == (m.n_queries, m.n_classes + 1)
-              and boxes.shape == (m.n_queries, 4)
+              and logits.shape == want_logits and boxes.shape == want_boxes
               and np.isfinite(logits).all() and np.isfinite(boxes).all())
         bad += not ok
     return bad

@@ -2,20 +2,24 @@
 
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a
 configuration (``configs/<name>.json``, found through the manifest's
-``configs[].file``) under a traffic mix (``traffic/<name>.json``). Every
-metric is a reader of its own, ``metrics/<name>.py``. Nothing here names
-a cell, a configuration, a traffic mix or a metric, so a later cell or
-metric is added with files and manifest entries alone.
+``configs[].file``) under a traffic mix (``traffic/<name>.json``). The
+configuration names its architecture module (``arch/<name>.py``, see
+``model.py``), which builds the program's server and holds the
+architecture's weights, reference and work counts. Every metric is a
+reader of its own, ``metrics/<name>.py``. Nothing here names a cell, a
+configuration, an architecture, a traffic mix or a metric, so a later
+cell, architecture or metric is added with files and manifest entries
+alone.
 
 A run:
 
 1. set-up (``setup_s``, from process start): the chip is checked, JAX's
    compilation cache is placed in the checkout, the weights are made on
-   the device from the seed, ``DetrServeEngine`` compiles (or loads) its
-   one bucket, the run's images are made, ``WARM_BATCHES`` batches are
-   served at once to warm the path (the gap between their completions is
-   one batch's time on the device), and the objects set-up made are
-   frozen out of garbage collection;
+   the device from the seed, the architecture module builds the
+   program's server, which compiles (or loads) its buckets, the run's
+   images are made, ``WARM_BATCHES`` batches are served at once to warm
+   the path (which measures one batch's time, ``warm_batch_s``), and the
+   objects set-up made are frozen out of garbage collection;
 2. the window (``--seconds``): the traffic drives ``submit`` and
    ``step`` on this thread, with up to ``AHEAD_S`` of device work
    dispatched (never fewer than ``WARM_BATCHES`` batches), so that the
@@ -47,9 +51,11 @@ from typing import Optional
 import numpy as np
 
 from benchmarks.chip import check, model as model_lib, traffic as traffic_lib
+from benchmarks.chip.model import Refused
 
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
+BENCH = HERE.relative_to(ROOT)          # the benchmark's files in a checkout
 DRAIN_S = 60.0
 # batches served at once in the warm-up, and the least number the window
 # keeps dispatched: one running and one queued behind it
@@ -59,10 +65,6 @@ WARM_BATCHES = 2
 # that is shorter, and nothing waits behind a dispatch queue of no end
 AHEAD_S = 6.0
 COMPILE_EVENT = "/jax/core/compile/"
-
-
-class Refused(RuntimeError):
-    """The run cannot be measured here; no result is printed."""
 
 
 def log(msg: str) -> None:
@@ -96,14 +98,18 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     conf = {c["name"]: c for c in man["configs"]}[w["config"]]
     return make_cell(name, root / conf["file"], w["traffic"], int(w["chips"]),
                      _for_cell(man["end_to_end"], name),
-                     _for_cell(man["per_layer"], name))
+                     _for_cell(man["per_layer"], name), root=root)
 
 
 def make_cell(name: str, config_file: Path, traffic: str, chips: int = 1,
-              end_to_end: tuple = (), per_layer: tuple = ()) -> Cell:
-    """A cell from a configuration file and a traffic name."""
-    m, raw = model_lib.load(config_file)
-    tr = json.loads((HERE / "traffic" / f"{traffic}.json").read_text())
+              end_to_end: tuple = (), per_layer: tuple = (), *,
+              root: Path = ROOT) -> Cell:
+    """A cell from a configuration file and a traffic name; its
+    architecture module and traffic file are those of the checkout at
+    ``root``."""
+    m, raw = model_lib.load(config_file, root / BENCH / "arch")
+    tr = json.loads((root / BENCH / "traffic" / f"{traffic}.json")
+                    .read_text())
     return Cell(name, chips, m, raw, tr, list(end_to_end), list(per_layer))
 
 
@@ -139,51 +145,6 @@ def enable_compile_cache(root: Path) -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
-
-
-def program_config(m: model_lib.Model):
-    """The detector config that the program serves for ``m``."""
-    import jax.numpy as jnp
-    from repro.core.detector import DetectorConfig
-    from repro.core.encoder import EncoderConfig
-    from repro.core.msdeform_attn import MSDeformAttnConfig
-    from repro.msda.decoder import MSDADecoderConfig
-    dt = jnp.dtype(m.dtype)
-    d = m.defa
-    attn = MSDeformAttnConfig(
-        d_model=m.d_model, n_heads=m.n_heads, n_levels=m.n_levels,
-        n_points=m.n_points, pap_mode="topk" if d else "off",
-        pap_keep=d.pap_keep if d else m.n_lp,
-        fwp_mode="compact" if d else "off",
-        fwp_k=d.fwp_k if d else 1.0,
-        fwp_capacity=d.fwp_capacity if d else 1.0,
-        range_narrow=d.range_narrow if d else None,
-        act_bits=d.act_bits if d else None,
-        weight_bits=d.weight_bits if d else None, dtype=dt)
-    cfg = DetectorConfig(
-        encoder=EncoderConfig(attn=attn, n_blocks=m.enc_layers,
-                              d_ffn=m.d_ffn, dtype=dt),
-        img_size=m.input_size, n_classes=m.n_classes,
-        backbone_width=m.backbone_width, dtype=dt,
-        decoder=MSDADecoderConfig(n_layers=m.dec_layers,
-                                  n_queries=m.n_queries, d_ffn=m.d_ffn,
-                                  dtype=dt))
-    if tuple(cfg.level_shapes) != m.level_shapes:
-        raise Refused(f"the program's pyramid {cfg.level_shapes} is not "
-                      f"the configuration's {m.level_shapes}")
-    return cfg
-
-
-def check_layout(params, cfg) -> None:
-    """The benchmark's weights have the program's tree, shapes and dtypes."""
-    import jax
-    from repro.core.detector import init_detector
-    want = jax.eval_shape(lambda: init_detector(jax.random.PRNGKey(0), cfg))
-    got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
-    want = jax.tree.map(lambda a: (a.shape, a.dtype), want)
-    if got != want:
-        raise Refused("the benchmark's weight tree differs from the "
-                      "program's init_detector tree")
 
 
 @dataclasses.dataclass
@@ -354,10 +315,14 @@ class _Drive:
 def warm_batch_s(reqs: list, max_batch: int) -> float:
     """One batch's device time: the gap between the last completions of
     the warm-up's first two batches, the second dispatched behind the
-    first."""
+    first. Floored at the mean time of the two from the first submit to
+    the second's last completion, which no stall can make shorter than
+    the device took: a misread gap (the first completion held up behind
+    a host stall) then keeps no more than ``AHEAD_S`` of real work
+    dispatched."""
     last = [max(r.t_done for r in reqs[i:i + max_batch])
             for i in range(0, 2 * max_batch, max_batch)]
-    return last[1] - last[0]
+    return max(last[1] - last[0], (last[1] - reqs[0].t_submit) / 2)
 
 
 def service_gaps(recs: list, batch_s: float, k: int = 3) -> list:
@@ -416,21 +381,14 @@ def serve_window(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
 def _serve(cell, seed, seconds, trace, t_start, dev, compiles):
     import jax
-    from benchmarks.chip import weights
-    from repro.serve.engine import DetrRequest, DetrServeEngine
+    from repro.serve.engine import DetrRequest
     m, tr = cell.model, cell.traffic
     mark = lambda what: log(f"[setup] {what} at "
                             f"{time.perf_counter() - t_start:.3f}s")
     mark("chip and cache ready")
-    cfg = program_config(m)
-    params = jax.block_until_ready(weights.make_params(seed, m))
-    check_layout(params, cfg)
+    params = jax.block_until_ready(m.arch.make_params(seed, m))
     mark("weights made")
-    engine = DetrServeEngine(cfg, params, max_batch=int(tr["max_batch"]),
-                             backend="auto", resolutions=(m.input_size,))
-    from repro.msda.plan import plan_for
-    enc_plan = plan_for(cfg.encoder.attn, cfg.level_shapes, "auto")
-    log(f"[setup] encoder plan: {enc_plan.describe()}")
+    engine = m.arch.engine(m, params, int(tr["max_batch"]))
     log(f"[setup] decoder plan: {engine.describe()}")
     log(f"[setup] bucket compile_seconds={engine.compile_seconds!r}")
     mark("engine built")

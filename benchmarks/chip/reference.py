@@ -1,12 +1,14 @@
-"""Plain float32 reference of the served detector, one image at a time.
+"""The plain float32 reference's shared parts: Deformable-DETR's
+deformable transformer, one image at a time.
 
 Written from the papers and the configuration alone; it imports nothing
-of the program. Deformable-DETR (arXiv:2010.04159): a conv pyramid, six
-encoder blocks of multi-scale deformable attention (MSDA) with post-norm
-FFNs, six decoder layers (self-attention, MSDA cross-attention on the
-encoder memory, FFN) from 300 learned queries, and class and box heads.
-With ``defa`` set, DEFA's semantics (arXiv:2403.10913) apply in every
-MSDA call:
+of the program. Deformable-DETR (arXiv:2010.04159): encoder blocks of
+multi-scale deformable attention (MSDA) with post-norm FFNs over the
+flattened pyramid, and decoder layers (self-attention, MSDA
+cross-attention on the encoder memory, FFN) over learned queries. An
+architecture module (``arch/<name>.py``) puts its feature extractor,
+embeddings and heads around these parts. With ``defa`` set, DEFA's semantics
+(arXiv:2403.10913) apply in every MSDA call:
 
 * INT12 fake quantisation, symmetric and per tensor, of the sampling
   weights (logit, offset, value and output projections), of the
@@ -31,11 +33,10 @@ follows are listed under ``departures`` in the configuration file.
 Every matmul and conv runs at ``Precision.HIGHEST``. MSDA is computed in
 blocks of queries so that a 512-px image fits beside nothing else.
 
-``lowp`` rounds every matmul and conv operand and the value table
-through a lower precision (the control; see ``check.py``)."""
+``lo`` rounds every matmul and conv operand and the value table through
+a lower precision (the control: ``round_fp8``; see ``check.py``)."""
 from __future__ import annotations
 
-import functools
 from typing import Callable, Optional
 
 import jax
@@ -49,7 +50,7 @@ QUERY_BLOCK = 2048
 Round = Callable[[jnp.ndarray], jnp.ndarray]
 
 
-def _ident(x):
+def ident(x):
     return x
 
 
@@ -71,26 +72,18 @@ def fake_quant(x: jnp.ndarray, bits: Optional[int],
     return jnp.clip(jnp.round(x / s), -qmax - 1, qmax) * s
 
 
-def _mm(a, b, lo: Round):
+def mm(a, b, lo: Round):
     return jnp.matmul(lo(a), lo(b), precision=HI)
 
 
-def _linear(p, x, lo: Round):
-    return _mm(x, p["w"], lo) + p["b"]
+def linear(p, x, lo: Round):
+    return mm(x, p["w"], lo) + p["b"]
 
 
-def _ln(p, x, eps=1e-5):
+def ln(p, x, eps=1e-5):
     mu = jnp.mean(x, -1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
     return (x - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def _conv_s2(p, x, lo: Round):
-    """3x3 conv, stride 2, SAME padding; x (C, H, W)."""
-    y = jax.lax.conv_general_dilated(
-        lo(x)[None], lo(p["w"]), (2, 2), "SAME",
-        dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=HI)[0]
-    return y + p["b"][:, None, None]
 
 
 def sine_embed(h: int, w: int, d: int) -> jnp.ndarray:
@@ -122,8 +115,8 @@ class Geometry:
         self.n_in = int(sum(sizes))
 
 
-def _msda(p: dict, m: Model, geo: Geometry, query, refs, table,
-          lo: Round, count: bool):
+def msda(p: dict, m: Model, geo: Geometry, query, refs, table,
+         lo: Round, count: bool):
     """One MSDA call. query (Nq, D), refs (Nq, 2) in [0, 1], table
     (N_in, H, Dh) with pruned rows already zero. Returns (out (Nq, D),
     per-pixel sampling counts (N_in,) or None)."""
@@ -134,14 +127,14 @@ def _msda(p: dict, m: Model, geo: Geometry, query, refs, table,
     wq = lambda w: fake_quant(w, bits_w)
     d = m.d_model
 
-    logits = _mm(query, wq(p["attn_w"]).reshape(d, h_ * lp), lo)
+    logits = mm(query, wq(p["attn_w"]).reshape(d, h_ * lp), lo)
     logits = logits.reshape(nq, h_, lp) + p["attn_b"]
     probs = fake_quant(jax.nn.softmax(logits, -1), bits_a)
     if m.defa:
         probs, point = jax.lax.top_k(probs, pts)
     else:
         point = jnp.broadcast_to(jnp.arange(lp), probs.shape)
-    offs = _mm(query, wq(p["offs_w"]).reshape(d, h_ * lp * 2), lo)
+    offs = mm(query, wq(p["offs_w"]).reshape(d, h_ * lp * 2), lo)
     offs = offs.reshape(nq, h_, lp, 2) + p["offs_b"].reshape(h_, lp, 2)
     offs = jnp.take_along_axis(offs, point[..., None], axis=2)
     level = point // m.n_points
@@ -190,8 +183,8 @@ def _msda(p: dict, m: Model, geo: Geometry, query, refs, table,
         sampled = sampled.reshape(-1, h_, dh)[:nq]
     else:
         sampled = block((pix, wgt))
-    out = _mm(sampled.reshape(nq, h_ * dh),
-              wq(p["out_w"]).reshape(h_ * dh, d), lo) + p["out_b"]
+    out = mm(sampled.reshape(nq, h_ * dh),
+             wq(p["out_w"]).reshape(h_ * dh, d), lo) + p["out_b"]
 
     freq = None
     if count:
@@ -218,13 +211,13 @@ def fwp_keep(m: Model, geo: Geometry, freq):
     return jnp.concatenate(kept_parts), jnp.concatenate(table_parts)
 
 
-def _value_table(p: dict, m: Model, x, keep, lo: Round):
+def value_table(p: dict, m: Model, x, keep, lo: Round):
     """(N_in, H, Dh) value rows of the memory ``x``; ``keep`` is None (all
     rows live) or (kept, in_table)."""
     bits_w = m.defa.weight_bits if m.defa else None
     bits_a = m.defa.act_bits if m.defa else None
     d = m.d_model
-    v = _mm(x, fake_quant(p["value_w"], bits_w).reshape(d, d), lo)
+    v = mm(x, fake_quant(p["value_w"], bits_w).reshape(d, d), lo)
     v = v.reshape(-1, m.n_heads, m.head_dim) + p["value_b"]
     if keep is None:
         v = fake_quant(v, bits_a)
@@ -235,78 +228,51 @@ def _value_table(p: dict, m: Model, x, keep, lo: Round):
     return lo(v)
 
 
-def _self_attention(p: dict, m: Model, h, pos, lo: Round):
+def self_attention(p: dict, m: Model, h, pos, lo: Round):
     n, d = h.shape
     nh, dh = m.n_heads, d // m.n_heads
-    q = _linear(p["self_q"], h + pos, lo).reshape(n, nh, dh)
-    k = _linear(p["self_k"], h + pos, lo).reshape(n, nh, dh)
-    v = _linear(p["self_v"], h, lo).reshape(n, nh, dh)
+    q = linear(p["self_q"], h + pos, lo).reshape(n, nh, dh)
+    k = linear(p["self_k"], h + pos, lo).reshape(n, nh, dh)
+    v = linear(p["self_v"], h, lo).reshape(n, nh, dh)
     att = jnp.einsum("qhd,khd->hqk", lo(q), lo(k), precision=HI)
     att = jax.nn.softmax(att / np.sqrt(dh), -1)
     out = jnp.einsum("hqk,khd->qhd", lo(att), lo(v), precision=HI)
-    return _linear(p["self_o"], out.reshape(n, d), lo)
+    return linear(p["self_o"], out.reshape(n, d), lo)
 
 
-def _inverse_sigmoid(x, eps=1e-5):
+def inverse_sigmoid(x, eps=1e-5):
     x = jnp.clip(x, eps, 1 - eps)
     return jnp.log(x) - jnp.log1p(-x)
 
 
-def forward(params: dict, m: Model, image, lo: Round = _ident):
-    """params: the served tree in float32; image (3, S, S) float32, the
-    request padded with zeros to the bucket. Returns (class logits
-    (Nq, C+1), boxes (Nq, 4) as cx, cy, w, h in [0, 1])."""
-    geo = Geometry(m)
-    x = jax.nn.relu(_conv_s2(params["stem"], image, lo))
-    levels = []
-    for name in ("c1", "c2", "c3", "c4"):
-        x = jax.nn.relu(_conv_s2(params[name], x, lo))
-        levels.append(x)
-    mem = jnp.concatenate([
-        _linear(pr, f.reshape(f.shape[0], -1).T, lo)
-        for f, pr in zip(levels, params["proj"])], 0)       # (N_in, D)
-    pos = jnp.concatenate([sine_embed(h, w, m.d_model)
-                           for h, w in geo.shapes], 0)
-    refs = jnp.asarray(np.concatenate(
-        [pixel_centres(h, w) for h, w in geo.shapes], 0))
-
+def encoder(blocks: list, m: Model, geo: Geometry, mem, pos, refs,
+            lo: Round):
+    """The encoder blocks over the flattened pyramid ``mem`` (N_in, D).
+    Returns (memory, keep): keep is what the last block's FWP decides for
+    the decoder's table (None without DEFA)."""
     keep = None
-    for blk in params["encoder"]["blocks"]:
-        table = _value_table(blk["attn"], m, mem, keep, lo)
-        out, freq = _msda(blk["attn"], m, geo, mem + pos, refs, table, lo,
-                          count=m.defa is not None)
-        mem = _ln(blk["ln1"], mem + out)
-        ff = _linear(blk["ffn2"], jax.nn.relu(_linear(blk["ffn1"], mem, lo)),
-                     lo)
-        mem = _ln(blk["ln2"], mem + ff)
+    for blk in blocks:
+        table = value_table(blk["attn"], m, mem, keep, lo)
+        out, freq = msda(blk["attn"], m, geo, mem + pos, refs, table, lo,
+                         count=m.defa is not None)
+        mem = ln(blk["ln1"], mem + out)
+        ff = linear(blk["ffn2"], jax.nn.relu(linear(blk["ffn1"], mem, lo)),
+                    lo)
+        mem = ln(blk["ln2"], mem + ff)
         if m.defa is not None:
             keep = fwp_keep(m, geo, freq)
-
-    dec = params["decoder"]
-    table = _value_table(dec["value"], m, mem, keep, lo)
-    qpos = dec["query_pos"]
-    h = dec["tgt_embed"]
-    ref = jax.nn.sigmoid(_linear(dec["ref_head"], qpos, lo))
-    for layer in dec["layers"]:
-        h = _ln(layer["ln_sa"], h + _self_attention(layer, m, h, qpos, lo))
-        out, _ = _msda(layer["cross"], m, geo, h + qpos, ref, table, lo,
-                       count=False)
-        h = _ln(layer["ln1"], h + out)
-        ff = _linear(layer["ffn2"], jax.nn.relu(_linear(layer["ffn1"], h, lo)),
-                     lo)
-        h = _ln(layer["ln2"], h + ff)
-        ref = jax.nn.sigmoid(_inverse_sigmoid(ref)
-                             + _linear(layer["ref_delta"], h, lo))
-    logits = _linear(params["cls_head"], h, lo)
-    raw = _linear(params["box_head"], h, lo)
-    cxy = jax.nn.sigmoid(raw[:, :2] + _inverse_sigmoid(ref))
-    boxes = jnp.concatenate([cxy, jax.nn.sigmoid(raw[:, 2:])], -1)
-    return logits, boxes
+    return mem, keep
 
 
-@functools.lru_cache(maxsize=4)
-def compiled(m: Model, control: bool = False):
-    """The jitted reference (``control``: operands rounded through
-    float8_e4m3fn) for one configuration."""
-    lo = round_fp8 if control else _ident
-    return jax.jit(lambda p, img: forward(p, m, img, lo))
+def decoder_layer(layer: dict, m: Model, geo: Geometry, h, qpos, ref, table,
+                  lo: Round):
+    """One decoder layer on the queries ``h`` (Nq, D) at reference points
+    ``ref`` (Nq, 2): self-attention, MSDA cross-attention on ``table``,
+    FFN, each post-norm."""
+    h = ln(layer["ln_sa"], h + self_attention(layer, m, h, qpos, lo))
+    out, _ = msda(layer["cross"], m, geo, h + qpos, ref, table, lo,
+                  count=False)
+    h = ln(layer["ln1"], h + out)
+    ff = linear(layer["ffn2"], jax.nn.relu(linear(layer["ffn1"], h, lo)),
+                lo)
+    return ln(layer["ln2"], h + ff)

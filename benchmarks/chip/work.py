@@ -10,7 +10,11 @@ them. Conventions:
 * pruned work does not count: PAP's dropped points are neither sampled
   nor given offsets (their attention logits are, since PAP needs them to
   choose), and an FWP-compacted table projects only its static capacity;
-* the backbone is counted as it runs: the repo's 5-conv stem.
+* the deformable transformer's counts are here; an architecture module
+  (``arch/<name>.py``) counts the rest of its forward as it runs
+  (``flops_per_image``) and lists its MSDA calls in program order
+  (``msda_calls``), which ``flops_per_image`` and ``msda_calls`` below
+  hand on to.
 
 One MSDA sampling call reads its value table once, its sampling
 locations (two float32 per point), its kept probabilities (one element
@@ -90,54 +94,24 @@ def decoder_call(m: Model) -> Work:
 
 
 def msda_calls(m: Model) -> list:
-    """The MSDA sampling calls of one image's forward, in program order:
-    the encoder blocks, then the decoder layers."""
-    return ([encoder_call(m, b) for b in range(m.enc_layers)]
-            + [decoder_call(m)] * m.dec_layers)
+    """The MSDA sampling calls of one image's forward, in program order."""
+    return m.arch.msda_calls(m)
 
 
-def _mm(n, k, o):
+def mm_flops(n, k, o):
     return 2.0 * n * k * o
-
-
-def backbone_flops(m: Model) -> float:
-    s, w = m.input_size, m.backbone_width
-    total, c_in = 0.0, 3
-    for stride in (2, 4, 8, 16, 32):                 # stem, c1..c4
-        total += _mm((s // stride) ** 2, c_in * 9, w)
-        c_in = w
-    return total
 
 
 def encoder_block_flops(m: Model, block: int) -> float:
     n, d, h = m.n_in, m.d_model, m.n_heads
-    return (_mm(table_rows(m, block), d, d)          # value projection
-            + _mm(n, d, h * m.n_lp)                  # attention logits
-            + _mm(n, d, h * m.points_kept * 2)       # kept offsets
+    return (mm_flops(table_rows(m, block), d, d)     # value projection
+            + mm_flops(n, d, h * m.n_lp)             # attention logits
+            + mm_flops(n, d, h * m.points_kept * 2)  # kept offsets
             + encoder_call(m, block).flops           # sampling
-            + _mm(n, d, d)                           # output projection
-            + _mm(n, d, m.d_ffn) + _mm(n, m.d_ffn, d))
-
-
-def decoder_flops(m: Model) -> float:
-    q, d, h = m.n_queries, m.d_model, m.n_heads
-    per_layer = (4 * _mm(q, d, d)                    # self-attn q, k, v, o
-                 + 2 * _mm(q, d, q)                  # scores and values
-                 + _mm(q, d, h * m.n_lp)
-                 + _mm(q, d, h * m.points_kept * 2)
-                 + decoder_call(m).flops
-                 + _mm(q, d, d)
-                 + _mm(q, d, m.d_ffn) + _mm(q, m.d_ffn, d)
-                 + _mm(q, d, 2))                     # reference refinement
-    table = _mm(table_rows(m, m.enc_layers), d, d)   # one shared table
-    return table + _mm(q, d, 2) + m.dec_layers * per_layer
+            + mm_flops(n, d, d)                      # output projection
+            + mm_flops(n, d, m.d_ffn) + mm_flops(n, m.d_ffn, d))
 
 
 def flops_per_image(m: Model) -> float:
     """Model FLOPs of one image's forward."""
-    d = m.d_model
-    proj = sum(_mm(h * w, m.backbone_width, d) for h, w in m.level_shapes)
-    heads = _mm(m.n_queries, d, m.n_classes + 1) + _mm(m.n_queries, d, 4)
-    return (backbone_flops(m) + proj
-            + sum(encoder_block_flops(m, b) for b in range(m.enc_layers))
-            + decoder_flops(m) + heads)
+    return m.arch.flops_per_image(m)

@@ -1,13 +1,16 @@
-"""The benchmark's plain reference against the program's float32 forward,
-and its control, at small sizes on the CPU."""
+"""The ``detr_stem`` architecture module at small sizes on the CPU: its
+plain reference against the program's float32 forward, its control, its
+weights' layout, and the configuration keys it refuses."""
 import dataclasses
+import json
 from pathlib import Path
 
 import jax
 import numpy as np
 import pytest
 
-from benchmarks.chip import check, harness, model, reference, weights
+from benchmarks.chip import check, model
+from benchmarks.chip.model import Refused
 
 DATA = Path(__file__).resolve().parent / "data"
 SEED = 3_000_000_011            # wider than 32 bits hold
@@ -31,13 +34,14 @@ def test_reference_is_the_program_in_float32(name):
     pruning and quantisation included) to rounding."""
     from repro.core.detector import detector_apply
     m, _ = load(name)
-    cfg32 = harness.program_config(dataclasses.replace(m, dtype="float32"))
-    params = weights.to_f32(weights.make_params(SEED, m))
-    img = check.padded(images(m, 1, 1)[0], m.input_size)
+    arch = m.arch
+    cfg32 = arch.program(dataclasses.replace(m, dtype="float32"))
+    params = arch.to_f32(arch.make_params(SEED, m))
+    img = arch.canvas(images(m, 1, 1)[0], m)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda p, x: detector_apply(
             p, cfg32, x, backend="jnp_gather")[:2])(params, img[None])
-    got = reference.compiled(m)(params, img)
+    got = arch.reference(m)(params, img)
     assert check.rel_err(got[0], want[0][0]) < 1e-5
     assert check.rel_err(got[1], want[1][0]) < 1e-5
 
@@ -53,5 +57,24 @@ def test_control_is_not_correct(name):
 
 def test_weights_have_the_programs_layout():
     m, _ = load("tiny-defa")
-    harness.check_layout(weights.make_params(SEED, m),
-                         harness.program_config(m))
+    m.arch.check_layout(m.arch.make_params(SEED, m), m.arch.program(m))
+
+
+@pytest.mark.parametrize("key, value", [
+    ("with_box_refine", True), ("two_stage", True), ("level_embed", True),
+    ("backbone", "resnet50"), ("class_head", "sigmoid_focal"),
+    ("bbox_embed_layers", 3), ("feature_strides", [8, 16, 32, 64]),
+    ("arch", None), ("arch", "no_such_module"), ("aux_loss", True)])
+def test_unimplemented_key_is_refused(tmp_path, key, value):
+    """A configuration that states what the module does not serve, or
+    names no module, is refused at load, naming the key; before modules
+    these loaded as the stem model."""
+    raw = json.loads((DATA / "tiny.json").read_text())
+    if value is None:
+        del raw[key]
+    else:
+        raw[key] = value
+    path = tmp_path / "conf.json"
+    path.write_text(json.dumps(raw))
+    with pytest.raises(Refused, match=key):
+        model.load(path)

@@ -1,14 +1,19 @@
 """The cell command and the manifest: what the contract of the benchmark
-asks of them, checked without a chip."""
+asks of them, checked without a chip; and a cell of a new architecture
+that joins with new files alone."""
+import hashlib
 import json
 import os
 import re
 import shutil
 import subprocess
 import sys
+import time
 from pathlib import Path
 
 import pytest
+
+from benchmarks.chip import harness
 
 ROOT = Path(__file__).resolve().parents[2]
 MAN = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -70,6 +75,8 @@ def test_every_entry_has_its_files():
         assert conf["name"] == c["name"]
         assert sorted(conf["reduced"]) == sorted(c["reduced"])
         assert conf["limits"], f"{c['name']} compares nothing"
+        assert (ROOT / "benchmarks/chip/arch" / f"{conf['arch']}.py") \
+            .exists()
     for w in MAN["workloads"]:
         assert (ROOT / "benchmarks/chip/traffic" / f"{w['traffic']}.json") \
             .exists()
@@ -102,3 +109,81 @@ def test_every_cell_reports_what_it_must():
 @pytest.mark.parametrize("w", MAN["workloads"], ids=lambda w: w["name"])
 def test_why_is_one_short_line(w):
     assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
+
+
+# detr_stem under another layout of its file: the transformer's sizes in a
+# group of their own
+NESTED = '''"""detr_stem with the transformer's sizes under "transformer"."""
+from pathlib import Path
+
+from benchmarks.chip import model
+
+base = model.arch_module(Path(__file__).with_name("detr_stem.py"))
+engine, make_params, to_f32 = base.engine, base.make_params, base.to_f32
+reference, canvas = base.reference, base.canvas
+output_shapes = base.output_shapes
+flops_per_image, msda_calls = base.flops_per_image, base.msda_calls
+
+
+def parse(raw):
+    rest = {k: v for k, v in raw.items() if k not in ("transformer", "arch")}
+    return base.parse({**rest, **raw["transformer"], "arch": "detr_stem"})
+'''
+TRANSFORMER = ("hidden_dim", "nheads", "enc_n_points", "dec_n_points",
+               "enc_layers", "dec_layers", "dim_feedforward", "num_queries")
+
+
+def _digests(root: Path) -> dict:
+    return {p.relative_to(root): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in root.rglob("*") if p.is_file()
+            and "__pycache__" not in p.parts}
+
+
+def test_a_new_architecture_joins_with_files_alone(tmp_path, monkeypatch):
+    """In a copy of the benchmark, one module under ``arch/``, one
+    configuration file naming it, one traffic file and manifest entries
+    make a cell that runs end to end (on the CPU, at tiny sizes) and comes
+    out correct; no file of the copy but the manifest changes."""
+    import repro  # noqa: F401  (the program, from this checkout's src)
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    for p in MAN["paths"]:
+        shutil.copytree(ROOT / p, tmp_path / p,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    before = _digests(tmp_path)
+    (tmp_path / "src").symlink_to(ROOT / "src")
+    bench = tmp_path / "benchmarks/chip"
+
+    (bench / "arch/detr_stem_nested.py").write_text(NESTED)
+    tiny = json.loads((ROOT / "tests/bench_chip/data/tiny.json").read_text())
+    conf = {k: v for k, v in tiny.items() if k not in TRANSFORMER}
+    conf.update(name="tiny-nested", arch="detr_stem_nested",
+                transformer={k: tiny[k] for k in TRANSFORMER}, reduced=[])
+    (bench / "configs/tiny-nested.json").write_text(json.dumps(conf))
+    (bench / "traffic/tiny-open.json").write_text(json.dumps(
+        {"loop": "open", "rate_per_s": 4.0, "schedule_seed": 5,
+         "max_batch": 1, "long_side": 64,
+         "aspects": [[4, 3, 0.5], [3, 4, 0.25], [1, 1, 0.25]]}))
+    man = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    man["configs"].append({"name": "tiny-nested", "source": "test",
+                           "file": "benchmarks/chip/configs/tiny-nested.json",
+                           "reduced": [], "why": "test"})
+    man["workloads"].append({"name": "tiny-nested.open",
+                             "config": "tiny-nested", "traffic": "tiny-open",
+                             "chips": 1, "why": "test"})
+    for m in man["end_to_end"]:
+        if m["name"] == "latency_p50_ms":
+            m["workloads"].append("tiny-nested.open")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(man))
+
+    monkeypatch.setattr(harness, "enable_compile_cache", lambda root: "off")
+    cell = harness.load_cell("tiny-nested.open", tmp_path)
+    assert Path(cell.model.arch.__file__) == bench / "arch/detr_stem_nested.py"
+    r = harness.run_cell("tiny-nested.open", 2 ** 33 + 9, 2.0, False,
+                         t_start=time.perf_counter(), root=tmp_path,
+                         require_chip=False)
+    assert r["correct"], r["checks"]
+    assert r["failed"] == 0 and r["attempted"] == 8
+    assert set(r["metrics"]) == {"latency_p50_ms", "setup_s"}
+    after = _digests(tmp_path)
+    assert {p for p in before if after.get(p) != before[p]} \
+        == {Path("BENCHMARK.json")}

@@ -6,7 +6,7 @@ from pathlib import Path
 import pytest
 
 from benchmarks.chip import model, work, xplane
-from benchmarks.chip.harness import (HERE, WARM_BATCHES, Rec, Run,
+from benchmarks.chip.harness import (AHEAD_S, HERE, WARM_BATCHES, Rec, Run,
                                     ahead_batches, metric_reader,
                                     service_gaps, warm_batch_s)
 
@@ -43,7 +43,7 @@ def test_detr_512_counts_are_the_hand_arithmetic():
     assert work.encoder_block_flops(m, 0) == block == 33_690_746_880
     backbone = 2 * 9 * 32 * (3 * 256 ** 2 + 32 * (128 ** 2 + 64 ** 2
                                                    + 32 ** 2 + 16 ** 2))
-    assert work.backbone_flops(m) == backbone
+    assert m.arch.backbone_flops(m) == backbone
     assert work.flops_per_image(m) == 209_932_013_568
 
 
@@ -117,15 +117,31 @@ def test_images_per_s_counts_the_batch_in_flight_by_its_share():
     assert metric_reader("images_per_s")(run) == run.images_per_s()
 
 
+def _warm(t_submit, t_done_first, t_done_second):
+    """Two warm-up batches of 4, submitted at ``t_submit``."""
+    return [type("Req", (), {"t_submit": t_submit, "t_done": t})
+            for t in [t_done_first + i * 1e-6 for i in range(4)]
+            + [t_done_second + i * 1e-6 for i in range(4)]]
+
+
 def test_dispatch_ahead_is_six_seconds_of_warm_up_batches():
-    """The warm-up's two batches of 4 end 0.5 s apart: the window keeps 12
-    batches dispatched; batches of 2.26 s keep the floor of 2."""
-    warm = [type("Req", (), {"t_done": t})
-            for t in [1.0 + i * 1e-6 for i in range(4)]
-            + [1.5 + i * 1e-6 for i in range(4)]]
+    """The warm-up's two batches of 4, submitted at 0.51 s, end 0.5 s
+    apart: the window keeps 12 batches dispatched; batches of 2.26 s keep
+    the floor of 2."""
+    warm = _warm(0.51, 1.0, 1.5)
     assert warm_batch_s(warm, 4) == pytest.approx(0.5)
     assert ahead_batches(0.5) == 12
     assert ahead_batches(2.26) == WARM_BATCHES == 2
+
+
+def test_a_misread_warm_up_dispatches_no_more_than_ahead_s():
+    """Batches of 0.5 s on the device, submitted at 0; the first one's
+    results held up behind a host stall until 2 ms before the second's:
+    the gap reads 2 ms, yet the window keeps no more than AHEAD_S of the
+    device's work dispatched."""
+    batch_s = warm_batch_s(_warm(0.0, 0.998, 1.0), 4)
+    assert batch_s >= 0.5
+    assert ahead_batches(batch_s) * 0.5 <= AHEAD_S
 
 
 def test_service_gaps_are_waits_between_back_to_back_batches():
